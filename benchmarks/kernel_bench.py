@@ -197,58 +197,6 @@ def bench_fused_step():
     return out
 
 
-def bench_paged_decode_gap():
-    """Decode-step attention gap: the pallas paged chunk kernel at S=1
-    (the shape every scheduler cycle issues per decode row) vs the jitted
-    jnp gather path, on IDENTICAL fragmented page tables. The tuning
-    lever is ``block_kv=True``: whole (ps, KV, hdw) pages per DMA and a
-    (B, nq, NP) grid — KVx fewer grid steps and page fetches than the
-    per-head default, same math (``blocked_vs_default_err`` is float-ULP
-    noise, exact 0.0 for fp pages). Interpret-mode wall time tracks
-    grid-step count, so the blocked variant's speedup here mirrors the
-    TPU-side DMA-descriptor saving; ``grid_steps_*`` is the structural
-    claim."""
-    out = {}
-    B, kv, g, hd, ps = 2, 2, 2, 32, 16
-    for ctx in (64, 256):
-        NP = -(-ctx // ps)
-        for bits, cont in ((0, "fp"), (8, "int8"), (4, "int4")):
-            rng = np.random.default_rng(ctx + bits)
-            kq, vq, ks, vs, pt = ref.make_fragmented_pool(rng, B, NP, ps,
-                                                          kv, hd, bits)
-            q = jnp.asarray(rng.normal(size=(B, 1, kv * g, hd)), jnp.float32)
-            qs = jnp.asarray(np.full((B,), ctx - 1, np.int32))
-            lens = jnp.asarray(np.full((B,), ctx, np.int32))
-            y = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, pt, qs,
-                                             lens, bits=bits)
-            yb = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, pt, qs,
-                                              lens, bits=bits, block_kv=True)
-            yr = ref.paged_kv_attention_chunk_ref(q, kq, vq, ks, vs, pt, qs,
-                                                  lens, bits=bits)
-            gather_fn = jax.jit(functools.partial(
-                ref.paged_kv_attention_chunk_ref, bits=bits))
-            out[f"ctx{ctx}-{cont}"] = {
-                "max_err_vs_gather": float(jnp.abs(y - yr).max()),
-                "blocked_vs_default_err": float(jnp.abs(yb - y).max()),
-                "pages": int(NP), "page_size": ps, "fragmented": True,
-                "grid_steps_default": int(B * kv * NP),
-                "grid_steps_blocked": int(B * NP),
-                "page_fetches_default": int(B * kv * NP * 2),
-                "page_fetches_blocked": int(B * NP * 2),
-                "gather_s": _timeit(gather_fn, q, kq, vq, ks, vs, pt, qs,
-                                    lens, reps=3),
-                "pallas_default_s": _timeit(
-                    lambda q, *a: ops.paged_kv_attention_chunk(
-                        q, *a, bits=bits),
-                    q, kq, vq, ks, vs, pt, qs, lens, reps=3),
-                "pallas_blocked_s": _timeit(
-                    lambda q, *a: ops.paged_kv_attention_chunk(
-                        q, *a, bits=bits, block_kv=True),
-                    q, kq, vq, ks, vs, pt, qs, lens, reps=3),
-            }
-    return out
-
-
 _STAGES = {
     "quant_cast": bench_quant_cast,
     "pack": bench_pack,
@@ -256,7 +204,6 @@ _STAGES = {
     "kv_attention": bench_kv_attention,
     "paged_prefill_chunk": bench_paged_prefill_chunk,
     "fused_step": bench_fused_step,
-    "paged_decode_gap": bench_paged_decode_gap,
 }
 
 
@@ -274,31 +221,6 @@ def run(*, verbose=True, only=None):
                 print(f"  {kname:19s} {cfg:18s} err/ok={err} ")
     save_json("kernel_bench.json" if only is None
               else f"kernel_bench_{'_'.join(sorted(only))}.json", res)
-    if "paged_decode_gap" in res:
-        # land the decode-gap numbers on the serving trend the driver diffs
-        import time as _time
-
-        from .paged_serve import _append_trajectory
-        rows = res["paged_decode_gap"]
-        speedups = [r["pallas_default_s"] / r["pallas_blocked_s"]
-                    for r in rows.values() if r["pallas_blocked_s"] > 0]
-        point = {"when": _time.strftime("%Y-%m-%d %H:%M:%S"),
-                 "arch": "kernel", "fast": False,
-                 "summary": {"decode_gap": {
-                     "configs": len(rows),
-                     "blocked_vs_default_err_max": max(
-                         r["blocked_vs_default_err"] for r in rows.values()),
-                     "max_err_vs_gather": max(
-                         r["max_err_vs_gather"] for r in rows.values()),
-                     "grid_step_ratio": rows[next(iter(rows))][
-                         "grid_steps_default"] / rows[next(iter(rows))][
-                         "grid_steps_blocked"],
-                     "blocked_speedup_geomean": float(
-                         np.exp(np.mean(np.log(speedups)))),
-                 }}}
-        path = _append_trajectory(point)
-        if verbose:
-            print(f"  decode-gap point appended to {path.rsplit('/', 1)[-1]}")
     return res
 
 

@@ -217,12 +217,9 @@ serving** (``launch.frontend`` + ``--tp``):
   lift aggregate goodput 0.79 -> 1.00 at 100% token agreement, with the
   affinity map absorbing the shared-prefix tenants and the store moving
   the hot chains across the pool.
-* the decode attention kernel grew a matching DMA-tuning knob:
-  ``block_kv=True`` (``ops.paged_kv_attention_chunk``) fetches whole
-  ``(ps, KV, hdw)`` pages per grid step — KVx fewer pipeline steps and
-  page fetches on the same math (``benchmarks.kernel_bench --only
-  paged_decode_gap``: 1.4x geomean faster at S=1, float-ULP agreement
-  with the per-head default, which stays the shipped reference).
+* the paged attention kernel fetches whole ``(ps, KV, hdw)`` pages per
+  grid step (``ops.paged_kv_attention_chunk``), the block shape the TPU
+  compiler accepts at every head count.
 
 Error/failure semantics: paged admission preflights a request's WORST-CASE
 page demand (prompt + max_new; with prefix sharing, only the non-shared

@@ -78,19 +78,15 @@ def paged_kv_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
 
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                              page_table, q_start, kv_len, *, bits: int = 8,
-                             block_q: int = 8, block_kv: bool = False,
-                             interpret=None):
+                             block_q: int = 8, interpret=None):
     """Variable-length (S >= 1) chunk attention over a paged quantized KV
     pool — the prefill-chunk generalization of ``paged_kv_attention`` (see
     kernels.paged_kv_attention for shapes). q: (B, S, H, hd); ``q_start``
-    (B,) is the absolute position of each row's first chunk query.
-    ``block_kv=True`` selects the KV-head-blocked pipeline (whole pages
-    per DMA; same math, fewer grid steps — see the kernel docstring)."""
+    (B,) is the absolute position of each row's first chunk query."""
     interpret = _default_interpret() if interpret is None else interpret
     return _paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                                      page_table, q_start, kv_len, bits=bits,
-                                     block_q=block_q, block_kv=block_kv,
-                                     interpret=interpret)
+                                     block_q=block_q, interpret=interpret)
 
 
 __all__ = ["quant_cast", "pack", "unpack", "qmatmul", "kv_attention",

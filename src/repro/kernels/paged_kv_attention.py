@@ -16,15 +16,16 @@ bucketed chunk prefill and decode through here under ``--attn-impl pallas``
 (``models.attention.route_paged_attention``), with the jnp gather path kept
 as the bitwise-reference mode.
 
-The page table, per-row chunk start positions, and per-row valid lengths
-ride as **scalar-prefetch** operands (`pltpu.PrefetchScalarGridSpec`): the
-BlockSpec index maps read ``page_table[b, p]`` to pick which pool page the
-next DMA fetches, so the gather happens in the pipeline, not the kernel body
-— the standard TPU paged attention pattern. In VMEM each page is unpacked
-(for sub-byte containers), dequantized by its page scale, and folded into
-the online-softmax state.
+The page table, per-row chunk start positions, per-row valid lengths and
+the per-row page scales ride as **scalar-prefetch** operands
+(`pltpu.PrefetchScalarGridSpec`): the BlockSpec index maps read
+``page_table[b, p]`` to pick which pool page the next DMA fetches, so the
+gather happens in the pipeline, not the kernel body — the standard TPU
+paged attention pattern. Each DMA brings one whole page (all KV heads); in
+VMEM it is unpacked (for sub-byte containers), dequantized by its page
+scale, and folded into the online-softmax state.
 
-Grid (B, KV, NQ, NP): NQ blocks of ``block_q`` chunk queries, NP pool pages
+Grid (B, NQ, NP): NQ blocks of ``block_q`` chunk queries, NP pool pages
 innermost sequential; (m, l, acc) scratch carries across pages per query
 block. Each key position is masked **causally against its per-row absolute
 query positions** (``q_start[b] + query index``) and against the row's
@@ -49,6 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.qtensor import unpack_bits
 
 NEG_INF = -1e30
+# float32 dots: the TPU's default rounds float32 operands to bf16, which put
+# the kernel 0.1 off its float32 oracle at qwen2-72b widths on a v5e
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _dequant(x, scale, *, bits, head_dim):
@@ -63,65 +67,15 @@ def _dequant(x, scale, *, bits, head_dim):
     return x.astype(jnp.float32) * scale
 
 
-def _chunk_kernel(pt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                  vs_ref, o_ref, m_ref, l_ref, acc_ref, *, np_, ps, bq, g,
+def _chunk_kernel(pt_ref, qs_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref,
+                  v_ref, o_ref, m_ref, l_ref, acc_ref, *, np_, ps, bq, kv, g,
                   bits, head_dim, sm_scale):
-    b, qb, p = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                  # (bq, G, hd)
-    q = q.reshape(bq * g, head_dim) * sm_scale
-    k = _dequant(k_ref[0, :, 0], ks_ref[0, 0], bits=bits,
-                 head_dim=head_dim)                      # (ps, hd)
-    v = _dequant(v_ref[0, :, 0], vs_ref[0, 0], bits=bits,
-                 head_dim=head_dim)                      # (ps, hd)
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq*G, ps)
-    pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    # causal mask against the ABSOLUTE position of each query row: flattened
-    # row r is chunk query r // G, at position q_start[b] + qb*bq + r // G
-    qrow = jax.lax.broadcasted_iota(jnp.int32, (bq * g, 1), 0) // g
-    q_pos = qs_ref[b] + qb * bq + qrow                   # (bq*G, 1)
-    s = jnp.where((pos <= q_pos) & (pos < len_ref[b]), s, NEG_INF)
-
-    m_prev = m_ref[...]                                  # (bq*G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    pexp = jnp.exp(s - m_new)                            # (bq*G, ps)
-    corr = jnp.exp(m_prev - m_new)                       # (bq*G, 1)
-    l_ref[...] = l_ref[...] * corr + pexp.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + \
-        jnp.dot(pexp, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(p == np_ - 1)
-    def _fin():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)
-                       ).reshape(bq, g, head_dim).astype(o_ref.dtype)
-
-
-def _chunk_kernel_kvblock(pt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref,
-                          ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                          np_, ps, bq, kv, g, bits, head_dim, sm_scale):
-    """KV-head-blocked variant of :func:`_chunk_kernel`: one grid step
-    fetches the WHOLE pool page — all KV heads, (1, ps, KV, hdw)
-    contiguous in the pool layout — instead of one head's (1, ps, 1, hdw)
-    slice, collapsing the grid from (B, KV, NQ, NP) to (B, NQ, NP). KV x
-    fewer pipeline steps and KV x fewer (KV x larger, fully contiguous)
-    page DMAs per query block, paid for with KV x the VMEM scratch and
-    per-step compute. The softmax state is carried for all heads at once
-    (rows = KV * bq * G); the per-head dots are a static python loop
-    (KV is small), so total MXU work is identical to the per-head grid.
-    The math is the same sequence of ops per row, but dot operands are
-    strided head-slices rather than contiguous blocks, so outputs agree
-    with the per-head kernel only to float ULPs (exact for fp pages) —
-    which is why ``block_kv`` defaults to off wherever bitwise serving
-    identity is pinned."""
+    """One grid step = one query block x one WHOLE pool page (all KV heads,
+    ``(1, ps, KV, hdw)`` contiguous in the pool layout). The softmax state
+    is carried for all heads at once (rows = KV * bq * G); the per-head
+    dots are a static python loop (KV is small). The page's dequant scales
+    are SMEM scalars (``ks_ref[b, p]``), gathered per row outside the
+    kernel so their size follows the page table, not the pool."""
     b, qb, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p == 0)
@@ -131,12 +85,13 @@ def _chunk_kernel_kvblock(pt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)                     # (bq, KV, G, hd)
-    k = _dequant(k_ref[0], ks_ref[0, 0], bits=bits,
+    k = _dequant(k_ref[0], ks_ref[b, p], bits=bits,
                  head_dim=head_dim)                      # (ps, KV, hd)
-    v = _dequant(v_ref[0], vs_ref[0, 0], bits=bits,
+    v = _dequant(v_ref[0], vs_ref[b, p], bits=bits,
                  head_dim=head_dim)                      # (ps, KV, hd)
 
-    # one causal/length mask, shared by every kv head
+    # one causal/length mask, shared by every kv head: flattened row r is
+    # chunk query r // G, at absolute position q_start[b] + qb*bq + r // G
     pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
     qrow = jax.lax.broadcasted_iota(jnp.int32, (bq * g, 1), 0) // g
     q_pos = qs_ref[b] + qb * bq + qrow                   # (bq*G, 1)
@@ -145,7 +100,7 @@ def _chunk_kernel_kvblock(pt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref,
     scores = []
     for h in range(kv):                                  # static unroll
         qh = (q[:, h].reshape(bq * g, head_dim) * sm_scale)
-        s = jnp.dot(qh, k[:, h].T,
+        s = jnp.dot(qh, k[:, h].T, precision=_F32,
                     preferred_element_type=jnp.float32)  # (bq*G, ps)
         scores.append(jnp.where(mask, s, NEG_INF))
     s = jnp.concatenate(scores, axis=0)                  # (KV*bq*G, ps)
@@ -157,7 +112,8 @@ def _chunk_kernel_kvblock(pt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref,
     l_ref[...] = l_ref[...] * corr + pexp.sum(axis=1, keepdims=True)
     upd = jnp.concatenate(
         [jnp.dot(pexp[h * bq * g:(h + 1) * bq * g], v[:, h],
-                 preferred_element_type=jnp.float32) for h in range(kv)],
+                 precision=_F32, preferred_element_type=jnp.float32)
+         for h in range(kv)],
         axis=0)                                          # (KV*bq*G, hd)
     acc_ref[...] = acc_ref[...] * corr + upd
     m_ref[...] = m_new
@@ -170,12 +126,10 @@ def _chunk_kernel_kvblock(pt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bits", "block_q", "block_kv",
-                                    "interpret"))
+                   static_argnames=("bits", "block_q", "interpret"))
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                              page_table, q_start, kv_len, *, bits: int = 8,
-                             block_q: int = 8, block_kv: bool = False,
-                             interpret: bool = False):
+                             block_q: int = 8, interpret: bool = False):
     """Variable-length chunk attention over a paged quantized KV pool.
 
     q: (B, S, H, hd) float — S chunk queries per sequence (S == 1: decode).
@@ -192,10 +146,9 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
         tail produce garbage outputs that no caller reads.
     bits must match the page container. Returns (B, S, H, hd) float32.
 
-    ``block_kv=True`` selects the KV-head-blocked pipeline (grid
-    (B, NQ, NP), whole pages per DMA — see :func:`_chunk_kernel_kvblock`):
-    same math, fewer/larger page fetches. Default off — the per-head grid
-    is the shipped reference whose outputs the serving identity tests pin.
+    Every block's last two dims are whole array dims (``(KV, hdw)`` for
+    pages, ``(G, hd)`` for queries), which is what the TPU compiler's
+    tiling rule accepts at any head count.
     """
     B, S, H, hd = q.shape
     P, ps, KV, hdw = k_pages.shape
@@ -204,91 +157,45 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
     bq = max(1, min(block_q, S))
     nq = -(-S // bq)
     sp = nq * bq
-    qg = jnp.moveaxis(q.reshape(B, S, KV, G, hd), 1, 2)  # (B, KV, S, G, hd)
-    if sp != S:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, sp - S), (0, 0), (0, 0)))
     sm_scale = float(1.0 / np.sqrt(hd))
     pt = jnp.asarray(page_table, jnp.int32)
     qs = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32).reshape(-1), (B,))
     lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (B,))
-
-    if block_kv:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,        # page_table, q_start, kv_len
-            grid=(B, nq, NP),
-            in_specs=[
-                pl.BlockSpec((1, bq, KV, G, hd),
-                             lambda b, qb, p, pt, qs, ln: (b, qb, 0, 0, 0)),
-                pl.BlockSpec((1, ps, KV, hdw),
-                             lambda b, qb, p, pt, qs, ln:
-                             (pt[b, p], 0, 0, 0)),
-                pl.BlockSpec((1, ps, KV, hdw),
-                             lambda b, qb, p, pt, qs, ln:
-                             (pt[b, p], 0, 0, 0)),
-                pl.BlockSpec((1, 1), lambda b, qb, p, pt, qs, ln:
-                             (pt[b, p], 0)),
-                pl.BlockSpec((1, 1), lambda b, qb, p, pt, qs, ln:
-                             (pt[b, p], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, KV, G, hd),
-                                   lambda b, qb, p, pt, qs, ln:
-                                   (b, qb, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((KV * bq * G, 1), jnp.float32),    # m
-                pltpu.VMEM((KV * bq * G, 1), jnp.float32),    # l
-                pltpu.VMEM((KV * bq * G, hd), jnp.float32),   # acc
-            ],
-        )
-        # the blocked kernel wants the (B, S, KV, G, hd) layout (whole
-        # token rows, all heads adjacent), not the per-head (B, KV, S, ...)
-        qb_in = q.reshape(B, S, KV, G, hd)
-        if sp != S:
-            qb_in = jnp.pad(qb_in, ((0, 0), (0, sp - S), (0, 0), (0, 0),
-                                    (0, 0)))
-        out = pl.pallas_call(
-            functools.partial(_chunk_kernel_kvblock, np_=NP, ps=ps, bq=bq,
-                              kv=KV, g=G, bits=bits, head_dim=hd,
-                              sm_scale=sm_scale),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, sp, KV, G, hd), jnp.float32),
-            interpret=interpret,
-        )(pt, qs, lens, qb_in, k_pages, v_pages,
-          k_scale.reshape(P, 1), v_scale.reshape(P, 1))
-        return out[:, :S].reshape(B, S, H, hd)
-
+    # per-row page scales (B, NP): SMEM holds what the table addresses, so
+    # the scalar operands stay small however large the pool grows
+    ks = jnp.take(k_scale.astype(jnp.float32), pt, axis=0)
+    vs = jnp.take(v_scale.astype(jnp.float32), pt, axis=0)
+    qb_in = q.reshape(B, S, KV, G, hd)
+    if sp != S:
+        qb_in = jnp.pad(qb_in, ((0, 0), (0, sp - S), (0, 0), (0, 0),
+                                (0, 0)))
+    # scalar operands: page_table, q_start, kv_len, k/v page scales
+    page_map = lambda b, qb, p, pt, qs, ln, ks, vs: (pt[b, p], 0, 0, 0)
+    row_map = lambda b, qb, p, pt, qs, ln, ks, vs: (b, qb, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,            # page_table, q_start, kv_len
-        grid=(B, KV, nq, NP),
+        num_scalar_prefetch=5,
+        grid=(B, nq, NP),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, G, hd),
-                         lambda b, k, qb, p, pt, qs, ln: (b, k, qb, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hdw),
-                         lambda b, k, qb, p, pt, qs, ln: (pt[b, p], 0, k, 0)),
-            pl.BlockSpec((1, ps, 1, hdw),
-                         lambda b, k, qb, p, pt, qs, ln: (pt[b, p], 0, k, 0)),
-            pl.BlockSpec((1, 1), lambda b, k, qb, p, pt, qs, ln:
-                         (pt[b, p], 0)),
-            pl.BlockSpec((1, 1), lambda b, k, qb, p, pt, qs, ln:
-                         (pt[b, p], 0)),
+            pl.BlockSpec((1, bq, KV, G, hd), row_map),
+            pl.BlockSpec((1, ps, KV, hdw), page_map),
+            pl.BlockSpec((1, ps, KV, hdw), page_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, G, hd),
-                               lambda b, k, qb, p, pt, qs, ln:
-                               (b, k, qb, 0, 0)),
+        out_specs=pl.BlockSpec((1, bq, KV, G, hd), row_map),
         scratch_shapes=[
-            pltpu.VMEM((bq * G, 1), jnp.float32),    # m
-            pltpu.VMEM((bq * G, 1), jnp.float32),    # l
-            pltpu.VMEM((bq * G, hd), jnp.float32),   # acc
+            pltpu.VMEM((KV * bq * G, 1), jnp.float32),    # m
+            pltpu.VMEM((KV * bq * G, 1), jnp.float32),    # l
+            pltpu.VMEM((KV * bq * G, hd), jnp.float32),   # acc
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, np_=NP, ps=ps, bq=bq, g=G,
+        functools.partial(_chunk_kernel, np_=NP, ps=ps, bq=bq, kv=KV, g=G,
                           bits=bits, head_dim=hd, sm_scale=sm_scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, sp, G, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, sp, KV, G, hd), jnp.float32),
         interpret=interpret,
-    )(pt, qs, lens, qg, k_pages, v_pages,
-      k_scale.reshape(P, 1), v_scale.reshape(P, 1))
-    return jnp.moveaxis(out[:, :, :S], 1, 2).reshape(B, S, H, hd)
+        name="paged_kv_attention",
+    )(pt, qs, lens, ks, vs, qb_in, k_pages, v_pages)
+    return out[:, :S].reshape(B, S, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))

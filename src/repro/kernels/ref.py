@@ -36,12 +36,7 @@ def pack_ref(q, bits: int):
     qu = q.astype(jnp.uint32) & mask
     grp = qu.reshape(*q.shape[:-1], q.shape[-1] // vpw, vpw)
     shifts = (jnp.arange(vpw, dtype=jnp.uint32) * bits)
-    word = jnp.bitwise_or.reduce(grp << shifts, axis=-1) \
-        if hasattr(jnp.bitwise_or, "reduce") else None
-    if word is None:
-        word = jnp.zeros(grp.shape[:-1], jnp.uint32)
-        for i in range(vpw):
-            word = word | (grp[..., i] << jnp.uint32(i * bits))
+    word = jnp.bitwise_or.reduce(grp << shifts, axis=-1)
     return jax.lax.bitcast_convert_type(word, jnp.int32)
 
 

@@ -1,10 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax import — jax locks the device
-count at first init, and the production meshes need 512 host devices.
+``main`` asks XLA for 512 host devices before the backend initializes (jax
+locks the device count at first use, and the production meshes need 512);
+importing this module leaves the environment alone.
 
 Per cell this produces (results/dryrun/<tag>/<mesh>_<arch>_<shape>.json):
   * compiled.memory_analysis()  -> per-device bytes (proves it fits v5e HBM)
@@ -21,6 +19,7 @@ import argparse
 import ast
 import dataclasses
 import json
+import os
 import time
 import traceback
 from functools import partial
@@ -183,6 +182,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -7,6 +7,16 @@ touch jax device state (smoke tests see 1 CPU device; only dryrun.py forces
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules and
+    hints of this repo leave layout to the partitioner, which the default
+    ``Explicit`` axes of the installed JAX refuse (e.g. a gather from a
+    sharded embedding table raises ``ShardingTypeError``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,19 +24,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     2-pod = 512-chip configuration."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has, as a 1-D data mesh (real training on
     this container: 1 CPU device)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small fake mesh for subprocess-based distribution tests."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_serving_mesh(tp: int = 1):
@@ -41,4 +51,4 @@ def make_serving_mesh(tp: int = 1):
         raise ValueError(f"tp must be >= 1, got {tp}")
     if n % tp != 0:
         raise ValueError(f"tp={tp} does not divide {n} visible devices")
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return _auto_mesh((n // tp, tp), ("data", "model"))

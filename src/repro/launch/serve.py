@@ -152,6 +152,7 @@ from ..parallel.sharding import (paged_pool_shardings, param_shardings,
                                  plan_for_mesh)
 from ..quant.apply import (build_model_quant, kv_profile_key,
                            transformer_layer_names)
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.telemetry import (MetricsRegistry, MetricsSnapshotter,
                                  SLOMonitor, make_tracer, metric_attr)
 from .mesh import make_serving_mesh
@@ -250,6 +251,19 @@ def _upload(x):
     later mutates in place (pos, tokens, page_table) is a data race. The
     snapshot is synchronous host work and nobody ever mutates it."""
     return jnp.asarray(np.array(x))
+
+
+def _jit_on_mesh(step, mesh):
+    """``jax.jit(step)``; with a serving mesh, ``step`` is traced under the
+    mesh's abstract context, which is how the paged attention kernel finds
+    the "model" axis to ``shard_map`` over (models.attention)."""
+    if mesh is None:
+        return jax.jit(step)
+
+    def on_mesh(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step(*args)
+    return jax.jit(on_mesh)
 
 
 class BatchedServer:
@@ -478,11 +492,12 @@ class BatchedServer:
         # the prefix cache namespaces its trie by this key
         self.profile_key = kv_profile_key(kv_profile, kv_bits=kv_bits,
                                           kv_scale_mode=kv_scale)
-        self.decode = jax.jit(make_decode_step(cfg, quant=self.quant,
-                                               attn_impl=attn_impl))
+        self.decode = _jit_on_mesh(make_decode_step(cfg, quant=self.quant,
+                                                    attn_impl=attn_impl),
+                                   mesh)
         self._chunk_prefill = (
-            jax.jit(make_chunk_prefill_step(cfg, quant=self.quant,
-                                            attn_impl=attn_impl))
+            _jit_on_mesh(make_chunk_prefill_step(cfg, quant=self.quant,
+                                                 attn_impl=attn_impl), mesh)
             if self.prefill_mode == "bucketed" else None)
         if fused not in ("on", "off"):
             raise ValueError(f"fused must be 'on' or 'off', got {fused!r}")
@@ -492,8 +507,9 @@ class BatchedServer:
                              "bucketed prefill path (paged cache, "
                              "attention-only arch, exact MoE routing)")
         self.fused = fused == "on"
-        self._fused = (jax.jit(make_fused_step(cfg, quant=self.quant,
-                                               attn_impl=attn_impl))
+        self._fused = (_jit_on_mesh(make_fused_step(cfg, quant=self.quant,
+                                                    attn_impl=attn_impl),
+                                    mesh)
                        if self.fused else None)
         if self.fused:
             # steady-state span constants: every row decodes (valid_len 1)
@@ -2061,6 +2077,7 @@ def main(argv=None):
                     help="scheduler cycles between JSONL snapshots "
                          "(with --metrics-out)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.trace_out or args.metrics_out:
         args.metrics = "on"
 

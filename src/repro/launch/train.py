@@ -39,6 +39,7 @@ from ..parallel.hints import activation_hints
 from ..parallel.sharding import (auto_batch_sharding, plan_for_mesh,
                                  state_shardings)
 from ..quant.apply import build_model_quant, transformer_layer_names
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.fault import StragglerMonitor, TrainSupervisor
 from .mesh import make_host_mesh, make_production_mesh
 from .steps import TrainHParams, init_train_state, make_train_step
@@ -92,6 +93,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = (make_host_mesh() if args.mesh == "host"

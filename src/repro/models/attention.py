@@ -160,14 +160,13 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
     * ``attn_impl="pallas"`` sends the chunk through
       ``kernels.paged_kv_attention`` (scalar-prefetch DMA over the page
       table, dequant in VMEM, per-row causal masking against absolute cache
-      positions). S == 1 takes the kernel's single-query-row special case
-      (the historical decode entry point). Per-page online softmax reorders
-      accumulation, so pallas == gather only within float tolerance.
+      positions). Per-page online softmax reorders accumulation, so
+      pallas == gather only within float tolerance. The kernel's mask is causal by construction, so a
+      non-causal config is refused.
     * ``attn_impl="gather"`` reads the pool through the jnp gather path —
       identical chunk accumulation order to the dense cache, which keeps
       paged serving bitwise-equal to the dense layout (the reference mode
-      the equivalence tests rely on). Non-causal configs also land here
-      (the kernel's mask is causal by construction).
+      the equivalence tests rely on).
 
     ``q``: (B, S, H, hd) post-RoPE queries; ``cache``: the pool dict AFTER
     this chunk's ``paged_cache_update`` write; ``cache_pos``: scalar or (B,)
@@ -180,21 +179,45 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
     B, S, H, hd = q.shape
     base = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1),
                             (B,))
-    if attn_impl == "pallas" and cfg.causal:
-        from ..kernels.ops import paged_kv_attention, paged_kv_attention_chunk
-        bits = {"int8": 8, "int4": 4, "fp": 0}[_paged_container(cache)]
-        args = (cache["k_pages"], cache["v_pages"], cache["k_scale"],
-                cache["v_scale"], page_table)
-        if S == 1:
-            out = paged_kv_attention(q[:, 0], *args, base + 1, bits=bits)
-            return out.reshape(B, 1, H, hd).astype(q.dtype)
-        out = paged_kv_attention_chunk(q, *args, base, base + S, bits=bits)
-        return out.astype(q.dtype)
+    if attn_impl == "pallas":
+        if not cfg.causal:
+            raise ValueError("the paged attention kernel masks causally; "
+                             "a non-causal config needs attn_impl='gather'")
+        return _paged_kernel_attention(q, cache, page_table, base)
     kd, vd = paged_cache_view(cache, page_table, head_dim=hd,
                               dtype=operand_dtype)
     return attend_chunked(q, kd, vd, positions, 0, causal=cfg.causal,
                           kv_len=base + S, chunk=cfg.attn_chunk,
                           operand_dtype=operand_dtype)
+
+
+def _paged_kernel_attention(q, cache, page_table, base):
+    """The Pallas paged kernel on the chunk ``q`` (B, S, H, hd). Under a
+    mesh context whose "model" axis divides the KV heads (tensor-parallel
+    serving: pools sharded on KV heads, queries on heads), the kernel runs
+    per shard through ``shard_map`` on that shard's heads and pages, so
+    the partitioner never gathers the pools onto one device."""
+    from ..kernels.ops import paged_kv_attention_chunk
+    S = q.shape[1]
+    bits = {"int8": 8, "int4": 4, "fp": 0}[_paged_container(cache)]
+
+    def kernel(q, k_pages, v_pages, k_scale, v_scale, pt, base):
+        return paged_kv_attention_chunk(q, k_pages, v_pages, k_scale,
+                                        v_scale, pt, base, base + S,
+                                        bits=bits)
+
+    args = (q, cache["k_pages"], cache["v_pages"], cache["k_scale"],
+            cache["v_scale"], page_table, base)
+    mesh = jax.sharding.get_abstract_mesh()
+    tp = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
+    if tp > 1 and cache["k_pages"].shape[-2] % tp == 0:
+        from jax.sharding import PartitionSpec as P
+        heads = P(None, None, "model", None)
+        kernel = jax.shard_map(
+            kernel, mesh=mesh,
+            in_specs=(heads, heads, heads, P(), P(), P(), P()),
+            out_specs=heads, check_vma=False)
+    return kernel(*args).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -153,7 +153,6 @@ def _sharded_dispatch(params, x, idx, w, cfg, plan):
         all-gathered over "data" per layer (transient) before compute,
       * the exchange is an explicit lax.all_to_all over the model axis.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, D = x.shape
@@ -187,10 +186,10 @@ def _sharded_dispatch(params, x, idx, w, cfg, plan):
                                   ep_axes=plan.model_axis)
         return out.reshape(Bl, Sl, D)
 
-    fn = shard_map(body, mesh=plan.mesh,
-                   in_specs=(ex_specs, x_spec, x_spec, x_spec),
-                   out_specs=x_spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=plan.mesh,
+                       in_specs=(ex_specs, x_spec, x_spec, x_spec),
+                       out_specs=x_spec,
+                       check_vma=False)
     return fn(params["experts"], x, idx, w)
 
 

@@ -368,7 +368,12 @@ def init_cache(cfg, batch, max_len, quant: Optional[ModelQuant] = None,
 # ---------------------------------------------------------------------------
 # Model init / forward
 # ---------------------------------------------------------------------------
+@partial(jax.jit, static_argnums=1)
 def init_model(key, cfg):
+    """Random parameters in ``cfg.param_dtype``. One jitted program, so
+    each leaf is drawn and cast in one fused pass: a bf16 model never holds
+    a float32 copy of a full-size leaf. Wrap it in ``jax.jit`` with
+    ``out_shardings`` to build each leaf straight onto its devices."""
     k_embed, k_head, k_mtp, k_layers = jax.random.split(key, 4)
     params: Dict[str, Any] = {}
     params["embed"] = init_embedding(k_embed, cfg.vocab_size, cfg.d_model,

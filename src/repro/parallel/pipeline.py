@@ -24,7 +24,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_bubble(num_stages: int, num_microbatches: int) -> float:
@@ -81,8 +80,8 @@ def gpipe_apply(stage_fn: Callable, stage_params, x_mb, *, mesh: Mesh,
             jnp.where(sid == S - 1, buf, jnp.zeros_like(buf)), axis)
         return out
 
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(P(axis), P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(P(axis), P()),
+                       out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_mb)

@@ -17,6 +17,7 @@ def run_py(body: str, devices: int = 8, timeout=420) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import warnings; warnings.filterwarnings("ignore")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ,
                PYTHONPATH=os.path.join(_ROOT, "src"))
@@ -28,14 +29,13 @@ def run_py(body: str, devices: int = 8, timeout=420) -> str:
 
 def test_quantized_allreduce_matches_mean():
     run_py("""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import quantized_allreduce
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_test_mesh((8,), ("d",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
-        f = shard_map(lambda x: quantized_allreduce(x[0], "d")[None],
-                      mesh=mesh, in_specs=P("d"), out_specs=P("d"),
-                      check_rep=False)
+        f = jax.shard_map(lambda x: quantized_allreduce(x[0], "d")[None],
+                          mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+                          check_vma=False)
         got = f(x)                       # every row = approx mean
         want = x.mean(axis=0)
         err = float(jnp.abs(got - want[None]).max())
@@ -51,7 +51,7 @@ def test_quantized_allreduce_matches_mean():
 def test_gpipe_pipeline_matches_sequential():
     run_py("""
         from repro.parallel.pipeline import gpipe_apply, pipeline_bubble
-        mesh = jax.make_mesh((8,), ("stage",))
+        mesh = make_test_mesh((8,), ("stage",))
         S, M, mb, D = 8, 4, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), S)
         Ws = jnp.stack([jax.random.normal(k, (D, D)) * 0.3 for k in ks])
@@ -98,7 +98,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
             losses1.append(float(m["loss"]))
 
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_test_mesh((2, 4), ("data", "model"))
         plan = plan_for_mesh(mesh)
         sh = state_shardings(jax.eval_shape(lambda: state0), plan)
         bsh = auto_batch_sharding(jax.eval_shape(lambda: lm_batch(dcfg, 0)),
@@ -129,7 +129,7 @@ def test_elastic_restore_across_meshes(tmp_path):
         cfg = get_smoke_config("deepseek-7b")
         hp = TrainHParams()
         state = init_train_state(jax.random.PRNGKey(3), cfg, hp)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_test_mesh((4, 2), ("data", "model"))
         sh = state_shardings(jax.eval_shape(lambda: state),
                              plan_for_mesh(mesh))
         state = jax.device_put(state, sh)
@@ -146,7 +146,7 @@ def test_elastic_restore_across_meshes(tmp_path):
             lambda k: init_train_state(k, cfg, hp), jax.random.PRNGKey(0))
         for shape, axes in [((2, 4), ("data", "model")),
                             ((8,), ("data",))]:
-            mesh = jax.make_mesh(shape, axes)
+            mesh = make_test_mesh(shape, axes)
             step, state, _ = elastic_restore({d!r}, tmpl, mesh)
             assert step == 17
             leaf = state["params"]["embed"]["table"]
@@ -162,7 +162,7 @@ def test_dryrun_cells_compile_on_test_mesh():
         from repro.configs.registry import get_smoke_config
         from repro.configs.shapes import ShapeConfig
         from repro.launch import dryrun
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
         tr = ShapeConfig("t", 128, 8, "train")
         dc = ShapeConfig("d", 128, 8, "decode")
         for arch in ["qwen1.5-32b", "deepseek-v3-671b", "xlstm-350m",
@@ -184,7 +184,7 @@ def test_moe_all_to_all_visible_in_hlo():
         from repro.models.moe import init_moe, moe_apply
         from repro.parallel.sharding import plan_for_mesh
         from repro.parallel.hints import activation_hints
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_test_mesh((2, 4), ("data", "model"))
         plan = plan_for_mesh(mesh)
         cfg = get_smoke_config("deepseek-v3-671b")
         p = init_moe(jax.random.PRNGKey(0), cfg)

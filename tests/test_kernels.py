@@ -243,12 +243,10 @@ def test_paged_chunk_block_q_invariance():
        g=st.sampled_from([1, 2]), s=st.sampled_from([1, 5, 8]),
        start=st.integers(0, 19), bits=st.sampled_from([0, 4, 8]))
 def test_paged_chunk_block_kv_matches_default(b, kv, g, s, start, bits):
-    """``block_kv=True`` is a DMA-tiling knob, not a numerics knob: the
-    KV-head-blocked grid must agree with the per-head default (and the
-    oracle) on the same fragmented tables, every container, decode (S=1)
-    and prefill shapes. Agreement is float-ULP, not bitwise — the blocked
-    kernel's dot operands are strided head-slices (see the kernel
-    docstring)."""
+    """The whole-page (all KV heads per DMA) kernel against the oracle on
+    fragmented tables, every container, decode (S=1) and prefill shapes,
+    at the serving page size; the decode entry point must equal the chunk
+    kernel's S=1 rows wherever the chunk is a single query."""
     rng = np.random.default_rng(b * 577 + s * 13 + start * 3 + bits)
     hd, ps = 32, 16
     starts = np.maximum(0, start - rng.integers(0, 4, b)).astype(np.int32)
@@ -259,13 +257,14 @@ def test_paged_chunk_block_kv_matches_default(b, kv, g, s, start, bits):
     lens = starts + s
     args = (q, kq, vq, ks, vs, jnp.asarray(pt), jnp.asarray(starts),
             jnp.asarray(lens))
-    blocked = ops.paged_kv_attention_chunk(*args, bits=bits, block_q=4,
-                                           block_kv=True)
-    default = ops.paged_kv_attention_chunk(*args, bits=bits, block_q=4)
-    np.testing.assert_allclose(blocked, default, rtol=1e-5, atol=1e-5)
+    out = ops.paged_kv_attention_chunk(*args, bits=bits, block_q=4)
     expect = ref.paged_kv_attention_chunk_ref(q, kq, vq, ks, vs, pt, starts,
                                               lens, bits=bits)
-    np.testing.assert_allclose(blocked, expect, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
+    if s == 1:
+        d = ops.paged_kv_attention(q[:, 0], kq, vq, ks, vs, jnp.asarray(pt),
+                                   jnp.asarray(lens), bits=bits)
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(out[:, 0]))
 
 
 def test_paged_decode_is_chunk_special_case():

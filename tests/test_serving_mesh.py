@@ -79,6 +79,25 @@ def test_serving_mesh_shapes_on_8_devices():
     """)
 
 
+def test_every_mesh_constructor_has_auto_axes():
+    """The installed JAX defaults ``jax.make_mesh`` to Explicit axes, under
+    which the embedding gather of a sharded table raises; every launch.mesh
+    constructor must hand the partitioner Auto axes."""
+    run_py("""
+        from jax.sharding import AxisType
+        from repro.launch import mesh as m
+        meshes = {"production": m.make_production_mesh(),
+                  "production_multi": m.make_production_mesh(multi_pod=True),
+                  "host": m.make_host_mesh(),
+                  "test": m.make_test_mesh(),
+                  "serving": m.make_serving_mesh(4)}
+        for name, mesh in meshes.items():
+            assert set(mesh.axis_types) == {AxisType.Auto}, \\
+                (name, mesh.axis_types)
+        print("AUTO_OK")
+    """, devices=512)
+
+
 # ---------------------------------------------------------------------------
 # Sharding specs (pure rules, 1-device mesh)
 # ---------------------------------------------------------------------------
