@@ -129,6 +129,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import List, Optional
 
@@ -256,10 +257,12 @@ def _upload(x):
 def _jit_on_mesh(step, mesh):
     """``jax.jit(step)``; with a serving mesh, ``step`` is traced under the
     mesh's abstract context, which is how the paged attention kernel finds
-    the "model" axis to ``shard_map`` over (models.attention)."""
+    the "model" axis to ``shard_map`` over (models.attention). The wrapper
+    keeps ``step``'s name, so the module is ``jit_<name>`` either way."""
     if mesh is None:
         return jax.jit(step)
 
+    @functools.wraps(step)
     def on_mesh(*args):
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             return step(*args)
@@ -286,7 +289,12 @@ class BatchedServer:
 
     ``metrics``: "on" records request-lifecycle spans on ``self.tracer``
     (Chrome-trace exportable) and enables the JSONL snapshot stream;
-    "off" (default) installs the no-op ``NullTracer``. The
+    "off" (default) installs the no-op ``NullTracer``. In both modes the
+    serving loop's spans (``serve.tick``, ``serve.admission``,
+    ``serve.admit``, ``serve.prefill_chunk``, ``serve.decode_span``,
+    ``serve.decode_step``, ``serve.span_fetch``, ``serve.dispatch``,
+    ``serve.retire``, ...) are profiler annotations, so a profiler session
+    sees them on the device trace's clock. The
     ``MetricsRegistry`` itself is ALWAYS live — counters are pure host
     bookkeeping outside every jitted program, so tokens are identical
     either way (subprocess-asserted, like ``--kv-adapt off``).
@@ -817,31 +825,35 @@ class BatchedServer:
         sequences writing disjoint pages, so stacking is bitwise-neutral
         per row (asserted in tests/test_serve_fast.py)."""
         n = len(rows)
-        chunk = np.zeros((n, bucket), np.int32)
-        starts = np.zeros((n,), np.int32)
-        valids = np.zeros((n,), np.int32)
-        pts = np.empty((n, self.np_max), np.int32)
-        for r, job in enumerate(rows):
-            off = job.start + job.done
-            toks = job.req.prompt[off:len(job.req.prompt) - 1]
-            valid = min(bucket, len(toks))
-            self._ensure_page(job.slot, off + valid - 1)
-            chunk[r, :valid] = toks[:valid]
-            starts[r], valids[r] = off, valid
-            pts[r] = self.page_table[job.slot]
-        # chunk/starts/valids/pts are private copies nobody mutates later,
-        # so plain asarray uploads are race-free (cf. _upload)
         with self.tracer.span("prefill_chunk",
                               args={"rows": n, "bucket": bucket,
                                     "step": self._clock}):
-            self.caches = self._chunk_prefill(
-                self.params, jnp.asarray(chunk), jnp.asarray(starts),
-                jnp.asarray(valids), self.caches, jnp.asarray(pts))
-        self.prefill_forwards += 1
-        self.program_launches += 1
-        for r, job in enumerate(rows):
-            job.done += int(valids[r])
-            self.pos[job.slot] = job.start + job.done
+            chunk = np.zeros((n, bucket), np.int32)
+            starts = np.zeros((n,), np.int32)
+            valids = np.zeros((n,), np.int32)
+            pts = np.empty((n, self.np_max), np.int32)
+            for r, job in enumerate(rows):
+                off = job.start + job.done
+                toks = job.req.prompt[off:len(job.req.prompt) - 1]
+                valid = min(bucket, len(toks))
+                self._ensure_page(job.slot, off + valid - 1)
+                chunk[r, :valid] = toks[:valid]
+                starts[r], valids[r] = off, valid
+                pts[r] = self.page_table[job.slot]
+            # chunk/starts/valids/pts are private copies nobody mutates
+            # later, so plain asarray uploads are race-free (cf. _upload)
+            chunk_dev, starts_dev, valids_dev, pts_dev = (
+                jnp.asarray(chunk), jnp.asarray(starts),
+                jnp.asarray(valids), jnp.asarray(pts))
+            with self.tracer.span("dispatch"):
+                self.caches = self._chunk_prefill(
+                    self.params, chunk_dev, starts_dev, valids_dev,
+                    self.caches, pts_dev)
+            self.prefill_forwards += 1
+            self.program_launches += 1
+            for r, job in enumerate(rows):
+                job.done += int(valids[r])
+                self.pos[job.slot] = job.start + job.done
 
     def _finish_job(self, job: _PrefillJob):
         """Seal a prefilled slot: clock at the last prompt token (which the
@@ -970,9 +982,13 @@ class BatchedServer:
                                     "prefill_rows": len(ready),
                                     "decode_rows": len(decode),
                                     "step": self._clock}):
-            nxt, _, self.caches = self._fused(
-                self.params, jnp.asarray(tokens), jnp.asarray(starts),
-                jnp.asarray(valids), self.caches, pt, jnp.asarray(emit))
+            tokens_dev, starts_dev, valids_dev, emit_dev = (
+                jnp.asarray(tokens), jnp.asarray(starts),
+                jnp.asarray(valids), jnp.asarray(emit))
+            with self.tracer.span("dispatch"):
+                nxt, _, self.caches = self._fused(
+                    self.params, tokens_dev, starts_dev, valids_dev,
+                    self.caches, pt, emit_dev)
         self.program_launches += 1
         self.cycles += 1
         self.prefill_forwards += 1
@@ -1260,7 +1276,9 @@ class BatchedServer:
                     self.metrics.counter("sched.defers").inc()
                     self.tracer.req_defer(queue[0].rid, self._clock)
                     return                # wait for live requests' pages
-                self._do_admit(i, queue.pop(0), info, jobs)
+                req = queue.pop(0)
+                with self.tracer.span("admit", args={"rid": req.rid}):
+                    self._do_admit(i, req, info, jobs)
                 break
 
     def _admit_slo(self, queue: List[Request], jobs: List[_PrefillJob],
@@ -1309,7 +1327,8 @@ class BatchedServer:
                 continue
             if verdict == "admit":
                 queue.pop(idx)
-                self._do_admit(free[0], req, info, jobs)
+                with self.tracer.span("admit", args={"rid": req.rid}):
+                    self._do_admit(free[0], req, info, jobs)
                 if speculative and spec_budget is not None:
                     spec_budget -= 1
                 if deferred:
@@ -1355,7 +1374,6 @@ class BatchedServer:
         rows of different requests stack into one forward)."""
         if not queue:
             return
-        self.metrics.histogram("sched.queue_depth").observe(len(queue))
         self.slo_monitor.note_queue_depth(len(queue))
         spec_budget: Optional[int] = None
         if self.predictor is not None:
@@ -1801,10 +1819,14 @@ class ServeLoop:
         self.finished = False
 
     def tick(self, limit_step: Optional[int] = None) -> bool:
-        """One scheduler cycle. Never advances ``clock`` past
-        ``limit_step`` (when given). Returns True while the loop is doing
-        work or moving its clock; False once fully drained (also sets
-        ``finished``)."""
+        """One scheduler cycle (span ``tick``). Never advances ``clock``
+        past ``limit_step`` (when given). Returns True while the loop is
+        doing work or moving its clock; False once fully drained (also
+        sets ``finished``)."""
+        with self.srv.tracer.span("tick"):
+            return self._tick(limit_step)
+
+    def _tick(self, limit_step: Optional[int]) -> bool:
         srv = self.srv
         pending, queue = self.pending, self.queue
         if not (pending or queue or self.live):
@@ -1864,65 +1886,72 @@ class ServeLoop:
                              args={"steps": span, "rows": len(live),
                                    "step": clock}):
             for _ in range(span):
-                if srv.paged:
+                with srv.tracer.span("decode_step"):
+                    if srv.paged:
+                        for i in live:
+                            srv._ensure_page(i, int(srv.pos[i]))
+                    pt = srv._page_table_dev() if srv.paged else None
+                    with srv.tracer.span("dispatch"):
+                        if srv.fused:
+                            # steady state: the SAME fused program as
+                            # admission rounds at S=1 — every row decodes,
+                            # every row emits. Bitwise-identical to
+                            # srv.decode (the gathers are identity copies;
+                            # see make_fused_step).
+                            nxt, _, srv.caches = srv._fused(
+                                srv.params, tokens_dev[:, None], pos_dev,
+                                srv._ones_dev, srv.caches, pt,
+                                srv._arange_dev)
+                        else:
+                            nxt, _, srv.caches = srv.decode(
+                                srv.params, tokens_dev, pos_dev,
+                                srv.caches, pt)
+                    srv.program_launches += 1
+                    srv.cycles += 1
+                    nxt.copy_to_host_async()
+                    fetches.append((nxt, tuple(srv.slots)))
+                    # idle slots hold their token (keeps runs reproducible
+                    # across layouts even when idle rows share MoE
+                    # capacity)
+                    tokens_dev = (nxt if all_live
+                                  else jnp.where(live_mask_dev, nxt,
+                                                 tokens_dev))
+                    pos_dev = pos_dev + live_inc
                     for i in live:
-                        srv._ensure_page(i, int(srv.pos[i]))
-                pt = srv._page_table_dev() if srv.paged else None
-                if srv.fused:
-                    # steady state: the SAME fused program as admission
-                    # rounds at S=1 — every row decodes, every row
-                    # emits. Bitwise-identical to srv.decode (the
-                    # gathers are identity copies; see make_fused_step).
-                    nxt, _, srv.caches = srv._fused(
-                        srv.params, tokens_dev[:, None], pos_dev,
-                        srv._ones_dev, srv.caches, pt,
-                        srv._arange_dev)
-                else:
-                    nxt, _, srv.caches = srv.decode(
-                        srv.params, tokens_dev, pos_dev, srv.caches,
-                        pt)
-                srv.program_launches += 1
-                srv.cycles += 1
-                nxt.copy_to_host_async()
-                fetches.append((nxt, tuple(srv.slots)))
-                # idle slots hold their token (keeps runs reproducible
-                # across layouts even when idle rows share MoE capacity)
-                tokens_dev = (nxt if all_live
-                              else jnp.where(live_mask_dev, nxt,
-                                             tokens_dev))
-                pos_dev = pos_dev + live_inc
-                for i in live:
-                    srv.pos[i] += 1
-                    srv.slot_gen[i] += 1
-                srv.decode_steps += 1
-                srv._gen_tokens += len(live)
-            # span boundary: materialize tokens, retire finishers
-            last_np = None
-            for nxt_dev, owners in fetches:
-                arr = np.asarray(nxt_dev)
-                last_np = arr
-                for i, req in enumerate(owners):
-                    if req is not None:
-                        if not req.out:
-                            srv.tracer.req_first_token(req.rid)
-                            srv.slo_monitor.note_first_token(req.rid)
-                        req.out.append(int(arr[i]))
+                        srv.pos[i] += 1
+                        srv.slot_gen[i] += 1
+                    srv.decode_steps += 1
+                    srv._gen_tokens += len(live)
+            # span boundary: materialize tokens (the host waits on the
+            # device here)
+            with srv.tracer.span("span_fetch"):
+                last_np = None
+                for nxt_dev, owners in fetches:
+                    arr = np.asarray(nxt_dev)
+                    last_np = arr
+                    for i, req in enumerate(owners):
+                        if req is not None:
+                            if not req.out:
+                                srv.tracer.req_first_token(req.rid)
+                                srv.slo_monitor.note_first_token(req.rid)
+                            req.out.append(int(arr[i]))
         if srv.pager is not None:
             # span boundary: resolve in-flight async page transfers —
             # their D2H copies ran concurrently with the decode span
             # above (the Chrome trace's pager track shows the overlap)
             srv.pager.drain()
-        for i in live:
-            srv.tokens[i] = int(last_np[i])
-            req = srv.slots[i]
-            if (srv.slot_gen[i] >= req.max_new
-                    or srv.pos[i] >= srv.max_len - 1):
-                req.done = True
-                srv.slots[i] = None
-                srv._release_slot(i)
-                # everyone retiring here hit exactly span's end: span
-                # is the min remaining capacity over live slots
-                srv._note_finish(req, clock + span)
+        with srv.tracer.span("retire"):
+            for i in live:
+                srv.tokens[i] = int(last_np[i])
+                req = srv.slots[i]
+                if (srv.slot_gen[i] >= req.max_new
+                        or srv.pos[i] >= srv.max_len - 1):
+                    req.done = True
+                    srv.slots[i] = None
+                    srv._release_slot(i)
+                    # everyone retiring here hit exactly span's end: span
+                    # is the min remaining capacity over live slots
+                    srv._note_finish(req, clock + span)
         self.clock = clock + span
         srv.slo_monitor.advance(span)
         if srv._snapshotter is not None:

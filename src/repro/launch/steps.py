@@ -100,6 +100,10 @@ def make_chunk_prefill_step(cfg, *, quant=None, attn_impl: str = "gather"):
     decode step consumes the last prompt token), which is why this wraps
     ``forward_hidden`` and not ``forward``.
 
+    The returned function is named ``chunk_prefill_step`` (likewise
+    ``decode_step`` and ``fused_step`` below), so its XLA module is
+    ``jit_chunk_prefill_step`` in compiles and profiler traces.
+
     ``attn_impl`` routes the chunk's attention reads exactly like decode
     ("gather" = jnp bitwise reference, "pallas" = the variable-length paged
     chunk kernel) — prefill and decode share ONE attention entry point
@@ -111,7 +115,8 @@ def make_chunk_prefill_step(cfg, *, quant=None, attn_impl: str = "gather"):
     never see a forward (O(suffix/bucket) admission), while the chunk's
     attention still reads the shared history through the same page table.
     """
-    def step(params, tokens, start_pos, valid_len, caches, page_table):
+    def chunk_prefill_step(params, tokens, start_pos, valid_len, caches,
+                           page_table):
         batch = {"tokens": tokens}
         _, aux = forward_hidden(params, batch, cfg, quant=quant,
                                 caches=caches, cache_pos=start_pos,
@@ -119,7 +124,7 @@ def make_chunk_prefill_step(cfg, *, quant=None, attn_impl: str = "gather"):
                                 kv_valid_len=valid_len)
         return aux["caches"]
 
-    return step
+    return chunk_prefill_step
 
 
 def make_fused_step(cfg, *, quant=None, attn_impl: str = "gather"):
@@ -152,8 +157,8 @@ def make_fused_step(cfg, *, quant=None, attn_impl: str = "gather"):
     path, which the subprocess identity test in tests/test_serve_fast.py
     asserts at kv-bits {0, 8, 4}."""
 
-    def step(params, tokens, start_pos, valid_len, caches, page_table,
-             emit_idx):
+    def fused_step(params, tokens, start_pos, valid_len, caches, page_table,
+                   emit_idx):
         batch = {"tokens": tokens}
         x, aux = forward_hidden(params, batch, cfg, quant=quant,
                                 caches=caches, cache_pos=start_pos,
@@ -168,7 +173,7 @@ def make_fused_step(cfg, *, quant=None, attn_impl: str = "gather"):
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, logits, aux["caches"]
 
-    return step
+    return fused_step
 
 
 def make_decode_step(cfg, *, quant=None, greedy: bool = True,
@@ -182,7 +187,7 @@ def make_decode_step(cfg, *, quant=None, greedy: bool = True,
     paged cache (see core.paged_kv); ``attn_impl`` ("gather" | "pallas")
     picks the paged attention backend (models.attention.gqa_apply)."""
 
-    def step(params, tokens, pos, caches, page_table=None):
+    def decode_step(params, tokens, pos, caches, page_table=None):
         batch = {"tokens": tokens[:, None]}
         _, logits, caches, _ = forward(params, batch, cfg, quant=quant,
                                        caches=caches, cache_pos=pos,
@@ -192,7 +197,7 @@ def make_decode_step(cfg, *, quant=None, greedy: bool = True,
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, logits, caches
 
-    return step
+    return decode_step
 
 
 def make_embed_decode_step(cfg, *, quant=None):

@@ -2,8 +2,8 @@
 
 The serving stack (paged pool, prefix cache, quant tier, host tier, SLO
 scheduler, fused step) grew one ad-hoc instance counter per feature and a
-``verbose=True`` print block. This module replaces that with two small,
-dependency-free primitives every layer shares:
+``verbose=True`` print block. This module replaces that with two small
+primitives every layer shares:
 
 * :class:`MetricsRegistry` — named **counters** (monotonic, resettable
   floats/ints), **gauges** (current-state values, either set directly or
@@ -32,12 +32,18 @@ dependency-free primitives every layer shares:
   (no-deadline requests count as met iff they completed unrejected).
 
 * :class:`NullTracer` — the disabled path: identical surface, every method
-  a no-op. Telemetry lives entirely OUTSIDE jitted code, so
+  a no-op except ``span``. Telemetry lives entirely OUTSIDE jitted code, so
   ``--metrics off`` is bitwise-identical to the pre-telemetry server by
   construction (the same contract ``--kv-adapt off`` keeps).
 
 * :class:`MetricsSnapshotter` — a periodic JSONL metrics stream: one
   ``registry.snapshot()`` line every N scheduler cycles.
+
+Both tracers' spans enter ``jax.profiler.TraceAnnotation("serve.<name>")``
+(:func:`annotation`), so whenever a profiler session runs the serving
+loop's phases sit on the device trace's clock, whatever the metrics mode.
+With no session attached an annotation records nothing and costs about a
+microsecond.
 
 Counter *migration* from legacy instance attributes is done with
 :class:`metric_attr`: a data descriptor that maps ``srv.prefill_forwards``
@@ -55,10 +61,12 @@ import math
 import time
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "MetricsSnapshotter", "Tracer", "NullTracer", "make_tracer",
-           "metric_attr", "default_registry", "percentile", "Ewma",
-           "SLOMonitor", "PAGER_TID"]
+           "metric_attr", "percentile", "Ewma", "SLOMonitor", "PAGER_TID",
+           "annotation"]
 
 logger = logging.getLogger(__name__)
 
@@ -67,6 +75,12 @@ logger = logging.getLogger(__name__)
 # proven), and per-track span nesting is an invariant elsewhere — so they
 # get their own tid, far above any 1+rid request track
 PAGER_TID = 1_000_000
+
+
+def annotation(name: str, args: Optional[dict] = None) -> TraceAnnotation:
+    """The profiler annotation of span ``name``: ``serve.<name>``, with
+    ``args`` (host values only) as the event's stats."""
+    return TraceAnnotation("serve." + name, **(args or {}))
 
 
 def percentile(values, p: float):
@@ -262,19 +276,6 @@ class MetricsRegistry:
             h.reset()
 
 
-_DEFAULT: Optional[MetricsRegistry] = None
-
-
-def default_registry() -> MetricsRegistry:
-    """The process-wide registry, for components not owned by a server.
-    Everything in the serving path injects an explicit registry instead —
-    two servers in one process (every A/B bench) must not share counters."""
-    global _DEFAULT
-    if _DEFAULT is None:
-        _DEFAULT = MetricsRegistry()
-    return _DEFAULT
-
-
 class metric_attr:
     """Data descriptor mapping a legacy instance attribute onto a registry
     counter, so ``obj.prefill_forwards += 1`` and bench-side hand-zeroing
@@ -450,7 +451,9 @@ class Tracer:
     """Span-based request-lifecycle tracer on a monotonic clock.
 
     Purely host-side bookkeeping — never touches device state, so enabling
-    it cannot change tokens. Events are Chrome trace-event dicts
+    it cannot change tokens. Every span also enters the profiler
+    annotation ``serve.<name>`` (:func:`annotation`), exactly as
+    :class:`NullTracer`'s do. Events are Chrome trace-event dicts
     (timestamps in microseconds since tracer construction):
 
     * ``X`` complete spans (``span()``/``req_span()`` context managers),
@@ -497,10 +500,12 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, *, tid: int = 0,
              args: Optional[dict] = None):
-        """Record one ``X`` complete event around the body."""
+        """Record one ``X`` complete event around the body, inside the
+        span's profiler annotation."""
         t0 = self._now_us()
         try:
-            yield
+            with annotation(name, args):
+                yield
         finally:
             ev = {"ph": "X", "name": name, "pid": 0, "tid": tid,
                   "ts": t0, "dur": max(0.0, self._now_us() - t0)}
@@ -663,24 +668,13 @@ class Tracer:
         return path
 
 
-class _NullSpan:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
     """The ``--metrics off`` tracer: the full :class:`Tracer` surface, every
-    method a no-op (span contexts are a shared reusable null context). No
-    events, no request records, no clock reads — the instrumented serving
-    path degenerates to attribute calls that do nothing, and since tracing
-    never touches jitted code anyway, off ≡ the pre-telemetry path
-    bitwise."""
+    method a no-op except the spans. No events and no request records; a
+    span is only its profiler annotation (:func:`annotation`), which
+    records nothing unless a profiler session runs and costs about a
+    microsecond (two with args) when none does. Tracing never touches
+    jitted code, so off ≡ the pre-telemetry path bitwise."""
 
     enabled = False
     events: List[dict] = []
@@ -689,10 +683,10 @@ class NullTracer:
         pass
 
     def span(self, name, *, tid=0, args=None):
-        return _NULL_SPAN
+        return annotation(name, args)
 
     def req_span(self, rid, name, args=None):
-        return _NULL_SPAN
+        return annotation(name, args)
 
     def req_arrive(self, rid, step, deadline_step=None):
         pass

@@ -19,8 +19,15 @@ run, and the metrics on/off bitwise-identity contract.
   {0, 8, 4} (subprocess, single-threaded XLA — same pattern as the other
   bitwise-identity suites); fused mode must keep program_launches ==
   cycles as read through the registry.
+* Under a profiler session the serving loop's spans appear as
+  ``serve.*`` host events, nested tick > admission > admit and
+  tick > decode_span > decode_step > dispatch, whatever the metrics mode;
+  the device programs lower as ``jit_decode_step``,
+  ``jit_chunk_prefill_step`` and ``jit_fused_step``, on a mesh too.
 """
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -551,3 +558,180 @@ def test_slo_monitor_advance_and_slowdown_clipping():
         SLOMonitor(MetricsRegistry(), window=0)
     with pytest.raises(ValueError):
         Ewma(alpha=0.0)
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler clock, and the device programs' names
+# ---------------------------------------------------------------------------
+def _profiled(fn, trace_dir):
+    """Run ``fn`` under a profiler session; return its result and the
+    ``serve.*`` host events as dicts (name, t0, t1, stats)."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    events.append({"name": ev.name[len("serve."):],
+                                   "t0": ev.start_ns,
+                                   "t1": ev.start_ns + ev.duration_ns,
+                                   "stats": dict(ev.stats)})
+    return out, events
+
+
+def _parent(ev, events):
+    """Name of the innermost other event that covers ``ev`` (None if
+    none)."""
+    best = None
+    for o in events:
+        if o is ev or not (o["t0"] <= ev["t0"] and ev["t1"] <= o["t1"]):
+            continue
+        if best is None or o["t1"] - o["t0"] < best["t1"] - best["t0"]:
+            best = o
+    return None if best is None else best["name"]
+
+
+def test_span_primitive_writes_profiler_events(tmp_path):
+    """Both tracers' spans (request-track ones too) enter the profiler
+    annotation ``serve.<name>`` with their args as stats."""
+    on, off = Tracer(), NullTracer()
+
+    def body():
+        with on.span("outer", args={"steps": 3}):
+            with off.span("inner", args={"rows": 2}):
+                with off.req_span(0, "offload"):
+                    pass
+            with on.req_span(1, "resume"):
+                pass
+    _, events = _profiled(body, tmp_path)
+    by = {e["name"]: e for e in events}
+    assert set(by) == {"outer", "inner", "offload", "resume"}
+    assert by["outer"]["stats"] == {"steps": 3}
+    assert by["inner"]["stats"] == {"rows": 2}
+    assert _parent(by["inner"], events) == "outer"
+    assert _parent(by["offload"], events) == "inner"
+    assert _parent(by["resume"], events) == "outer"
+    # the live tracer still keeps its Chrome events under the bare names
+    assert {e["name"] for e in on.events if e["ph"] == "X"} \
+        == {"outer", "resume"}
+
+
+# where each serving-loop span sits: the innermost span around it
+_SPAN_PARENTS = {
+    "tick": {None},
+    "admission": {"tick"},
+    "admit": {"admission"},
+    "prefill_chunk": {"admission"},
+    "fused_round": {"admission"},
+    "decode_span": {"tick"},
+    "decode_step": {"decode_span"},
+    "span_fetch": {"decode_span"},
+    "dispatch": {"decode_step", "prefill_chunk", "fused_round"},
+    "retire": {"tick"},
+}
+
+
+@pytest.mark.parametrize("fused", ["off", "on"])
+def test_profiler_trace_holds_serving_spans(smoke_model, tmp_path, fused):
+    """``metrics="off"``: a profiler session over a ServeLoop run still
+    holds every serving span, nested as the loop runs them, with the
+    host values the loop had at hand as args."""
+    cfg, params = smoke_model
+    srv = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
+                        page_size=8, prefill="bucketed", prefill_bucket=8,
+                        fused=fused, metrics="off")
+    rng = np.random.default_rng(5)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, 6 + 3 * i)
+                    .astype(np.int32), 3 + i) for i in range(3)]
+    srv.run([Request(9, reqs[0].prompt, 2)])        # compile outside
+    steps0 = srv.decode_steps
+
+    def serve():
+        loop = srv.start_loop(reqs)
+        ticks = 0
+        while loop.tick():
+            ticks += 1
+        return ticks
+    ticks, events = _profiled(serve, tmp_path)
+    assert all(r.done for r in reqs)
+    assert srv.tracer.events == []                 # off records nothing
+    names = {e["name"] for e in events}
+    expect = set(_SPAN_PARENTS) - {"fused_round" if fused == "off"
+                                   else "prefill_chunk"}
+    assert names == expect, names
+    for ev in events:
+        assert _parent(ev, events) in _SPAN_PARENTS[ev["name"]], ev
+    count = lambda n: sum(1 for e in events if e["name"] == n)
+    assert count("tick") == ticks + 1              # the draining tick too
+    assert sorted(e["stats"]["rid"] for e in events
+                  if e["name"] == "admit") == [0, 1, 2]
+    spans = [e for e in events if e["name"] == "decode_span"]
+    assert sum(e["stats"]["steps"] for e in spans) == count("decode_step")
+    assert all(e["stats"]["rows"] >= 1 for e in spans)
+    assert count("span_fetch") == len(spans)
+    launches = count("decode_step") + count("prefill_chunk") \
+        + count("fused_round")
+    assert count("dispatch") == launches
+    if fused == "off":
+        assert count("decode_step") == srv.decode_steps - steps0
+        assert all(e["stats"]["bucket"] <= 8 for e in events
+                   if e["name"] == "prefill_chunk")
+
+
+def test_chrome_export_keeps_span_names(smoke_model):
+    """``metrics="on"``: the Chrome export keeps the span names it always
+    had, bare (no ``serve.`` prefix), beside the new ones."""
+    cfg, params = smoke_model
+    srv = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
+                        page_size=8, prefill="bucketed", prefill_bucket=8,
+                        metrics="on")
+    rng = np.random.default_rng(6)
+    srv.run([Request(i, rng.integers(0, cfg.vocab_size, 9)
+                     .astype(np.int32), 3) for i in range(2)])
+    xs = [e for e in srv.tracer.chrome_trace()["traceEvents"]
+          if e["ph"] == "X"]
+    names = {e["name"] for e in xs}
+    assert {"admission", "prefill_chunk", "decode_span"} <= names
+    assert {"tick", "admit", "decode_step", "span_fetch", "dispatch",
+            "retire"} <= names
+    assert not any(n.startswith("serve.") for n in names)
+    _spans_disjoint_or_nested([e for e in xs if e["tid"] == 0])
+
+
+def test_device_programs_have_stable_names(smoke_model):
+    """The serving programs lower as ``jit_decode_step``,
+    ``jit_chunk_prefill_step`` and ``jit_fused_step`` — jitted directly
+    and through ``_jit_on_mesh`` on a 1-device mesh — never ``jit_step``."""
+    from repro.launch.mesh import make_serving_mesh
+    from repro.launch.serve import _jit_on_mesh
+    from repro.launch.steps import (make_chunk_prefill_step,
+                                    make_decode_step, make_fused_step)
+    cfg, params = smoke_model
+    srv = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
+                        page_size=8, prefill="bucketed", prefill_bucket=8,
+                        fused="on")
+    B, NP = srv.B, srv.np_max
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
+    pt = i32(B, NP)
+    programs = {
+        "decode_step": (make_decode_step,
+                        (i32(B), i32(B), srv.caches, pt)),
+        "chunk_prefill_step": (make_chunk_prefill_step,
+                               (i32(B, 8), i32(B), i32(B), srv.caches,
+                                pt)),
+        "fused_step": (make_fused_step,
+                       (i32(B, 8), i32(B), i32(B), srv.caches, pt,
+                        i32(B))),
+    }
+    for mesh in (None, make_serving_mesh(1)):
+        for name, (make, args) in programs.items():
+            fn = _jit_on_mesh(make(cfg, quant=srv.quant), mesh)
+            head = fn.lower(srv.params, *args).as_text().split("\n", 1)[0]
+            assert head.startswith(f"module @jit_{name} "), (mesh, head)
