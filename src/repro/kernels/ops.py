@@ -14,7 +14,7 @@ from . import ref
 from .kv_attention import kv_attention_decode
 from .paged_kv_attention import \
     paged_kv_attention_chunk as _paged_kv_attention_chunk
-from .paged_kv_attention import paged_kv_attention_decode
+from .paged_kv_attention import BLOCK_Q, paged_kv_attention_decode
 from .pack import pack_2d, unpack_2d, values_per_word
 from .quant_cast import quant_cast_2d
 from .quant_matmul import quant_matmul
@@ -78,7 +78,7 @@ def paged_kv_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
 
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                              page_table, q_start, kv_len, *, bits: int = 8,
-                             block_q: int = 8, interpret=None):
+                             block_q: int = BLOCK_Q, interpret=None):
     """Variable-length (S >= 1) chunk attention over a paged quantized KV
     pool — the prefill-chunk generalization of ``paged_kv_attention`` (see
     kernels.paged_kv_attention for shapes). q: (B, S, H, hd); ``q_start``

@@ -16,24 +16,47 @@ bucketed chunk prefill and decode through here under ``--attn-impl pallas``
 (``models.attention.route_paged_attention``), with the jnp gather path kept
 as the bitwise-reference mode.
 
+Grid ``(B, NQ, NB)``: NQ blocks of ``block_q`` chunk queries per row, and
+NB blocks of ``ppb`` page-table entries innermost (``ppb`` pages of ``ps``
+keys, at most 128 keys a block, fewer where a sub-byte container's unpack
+would outgrow VMEM; the last block may be ragged). The (m, l, acc)
+online-softmax scratch carries across a query block's page blocks.
+
 The page table, per-row chunk start positions, per-row valid lengths and
 the per-row page scales ride as **scalar-prefetch** operands
-(`pltpu.PrefetchScalarGridSpec`): the BlockSpec index maps read
-``page_table[b, p]`` to pick which pool page the next DMA fetches, so the
-gather happens in the pipeline, not the kernel body — the standard TPU
-paged attention pattern. Each DMA brings one whole page (all KV heads); in
-VMEM it is unpacked (for sub-byte containers), dequantized by its page
-scale, and folded into the online-softmax state.
+(`pltpu.PrefetchScalarGridSpec`). Each grid step fetches ``ppb`` whole
+pages (all KV heads each), one ``(1, ps, KV, hdw)`` block per slot: slot
+``j``'s BlockSpec index map reads ``page_table[b, e]`` for its entry ``e``,
+so the gather happens in the pipeline, which double-buffers every slot (a
+step's pages are fetched while the step before it computes). The pool is
+handed to the kernel once per slot; each slot's DMA moves one whole page,
+the one shape the TPU compiler takes for a lane-packed container too. In
+VMEM each page is unpacked (sub-byte containers), dequantized in float32 by
+its page scale, and the block is folded into the online softmax.
 
-Grid (B, NQ, NP): NQ blocks of ``block_q`` chunk queries, NP pool pages
-innermost sequential; (m, l, acc) scratch carries across pages per query
-block. Each key position is masked **causally against its per-row absolute
-query positions** (``q_start[b] + query index``) and against the row's
-``kv_len`` — partial last pages fall out of the length mask, padded chunk
-tails (positions past the row's real tokens) produce garbage rows that no
+Live extent (:func:`live_extent`): query block ``qb`` of row ``b`` can see
+the keys below ``min(kv_len[b], q_start[b] + (qb+1) * block_q)``, i.e. the
+first ``n = ceil(that / ps)`` table entries, at most all NP of them (a
+padded chunk may end past the table), and the kernel reads no other entry.
+Blocks past the extent cost neither DMA nor compute: their slots keep the
+entry they last held inside the extent (``_entry``), so the pipeline sees
+an unchanged block index and fetches nothing, and ``pl.when`` skips their
+body. In the extent's ragged last block the slots past ``n`` hold such a
+repeated live page; their keys fall out of the mask below, so they add
+exactly zero. Entry 0 is always inside the extent, so every query row meets
+a live key in its first block and a skipped block would have left (m, l,
+acc) unchanged: the skip is exact. The output is written at the extent's
+last block. Inside the extent each key position is masked **causally
+against its per-row absolute query positions** (``q_start[b] + query
+index``) and against the row's ``kv_len``, cut at the table's end (NP *
+ps) — partial last pages fall out of the length mask, padded chunk tails
+(positions past the row's real tokens) produce garbage rows that no
 caller reads (their pool writes were already scratch-redirected by
-``paged_update``). Unused page-table entries must point at a valid pool
-page (page 0 / scratch) — their positions are masked the same way.
+``paged_update``).
+
+Table entries past a row's extent are never read here, but the jnp gather
+path (``core.paged_kv.paged_gather``) still reads every entry, so unused
+entries must keep pointing at a valid pool page (page 0 / scratch).
 ``kv_len`` must be >= 1 per row with at least one real query, else the
 masked softmax degenerates.
 """
@@ -53,6 +76,50 @@ NEG_INF = -1e30
 # float32 dots: the TPU's default rounds float32 operands to bf16, which put
 # the kernel 0.1 off its float32 oracle at qwen2-72b widths on a v5e
 _F32 = jax.lax.Precision.HIGHEST
+# keys a grid step covers: one lane-width of scores per query row
+_BLOCK_KEYS = 128
+# chunk queries a grid step covers (prefill; decode runs one)
+BLOCK_Q = 8
+# VMEM for a block's lane-unpack: each stored word of a sub-byte page is
+# widened to a 128-lane row of its fields before they are regrouped
+_UNPACK_BYTES = 8 << 20
+
+
+def pages_per_block(page_size: int, num_table_pages: int, *, kv_heads: int,
+                    words: int, bits: int) -> int:
+    """Page-table entries one grid step fetches and attends: up to
+    ``_BLOCK_KEYS`` keys, fewer where a sub-byte container's unpack would
+    outgrow ``_UNPACK_BYTES`` (``words``: stored words a key and head)."""
+    keys = _BLOCK_KEYS
+    if 0 < bits < 8:
+        keys = min(keys, _UNPACK_BYTES // (kv_heads * words * 128 * 4))
+    return max(1, min(num_table_pages, keys // page_size))
+
+
+def live_extent(q_start, kv_len, qb, *, ps: int, bq: int,
+                num_table_pages: int, xp=jnp):
+    """Table entries query block ``qb`` of a row can see: ``ceil(min(kv_len,
+    q_start + (qb+1) * bq) / ps)``, at most the table's ``num_table_pages``
+    (a chunk whose padded tail runs past the table walks the whole table).
+    The one rule for the kernel's walk (``xp=jnp``, on scalars) and for the
+    host-side count :func:`page_walk` (``xp=np``, on arrays)."""
+    hi = xp.minimum(kv_len, q_start + (qb + 1) * bq)
+    return xp.minimum((hi + ps - 1) // ps, num_table_pages)
+
+
+def page_walk(q_start, kv_len, *, seq_len: int, num_table_pages: int,
+              page_size: int, block_q: int = BLOCK_Q):
+    """Host-side count for one call: ``(walked, table)``, the page-table
+    entries the kernel fetches (each query block's live extent, summed over
+    rows and query blocks) and the entries a walk of the whole table by
+    every query block would visit. ``block_q`` as passed to the kernel."""
+    bq = max(1, min(block_q, seq_len))
+    nq = -(-seq_len // bq)
+    n = live_extent(np.asarray(q_start, np.int64)[:, None],
+                    np.asarray(kv_len, np.int64)[:, None], np.arange(nq),
+                    ps=page_size, bq=bq, num_table_pages=num_table_pages,
+                    xp=np)
+    return int(n.sum()), n.size * num_table_pages
 
 
 def _dequant(x, scale, *, bits, head_dim):
@@ -67,88 +134,121 @@ def _dequant(x, scale, *, bits, head_dim):
     return x.astype(jnp.float32) * scale
 
 
-def _chunk_kernel(pt_ref, qs_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref,
-                  v_ref, o_ref, m_ref, l_ref, acc_ref, *, np_, ps, bq, kv, g,
-                  bits, head_dim, sm_scale):
-    """One grid step = one query block x one WHOLE pool page (all KV heads,
-    ``(1, ps, KV, hdw)`` contiguous in the pool layout). The softmax state
-    is carried for all heads at once (rows = KV * bq * G); the per-head
-    dots are a static python loop (KV is small). The page's dequant scales
-    are SMEM scalars (``ks_ref[b, p]``), gathered per row outside the
-    kernel so their size follows the page table, not the pool."""
-    b, qb, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _entry(i, j, n, ppb):
+    """Table entry slot ``j`` of page block ``i`` holds, for a query block
+    whose live extent is ``n`` entries: its own entry inside the extent;
+    past it, the last entry that slot held inside the extent (so a dead
+    block's slots keep their block index and the pipeline fetches
+    nothing), or the extent's last entry where the slot had none."""
+    last = jnp.where(j < n, j + ppb * ((n - 1 - j) // ppb), n - 1)
+    return jnp.minimum(i * ppb + j, last)
 
-    @pl.when(p == 0)
+
+def _chunk_kernel(pt_ref, qs_ref, len_ref, ks_ref, vs_ref, q_ref, *refs,
+                  ppb, ps, bq, np_, kv, g, bits, head_dim, sm_scale):
+    """One grid step = one query block x one block of ``ppb`` page-table
+    entries (each page ``(1, ps, KV, hdw)``, all KV heads, contiguous in
+    the pool layout). The softmax state is carried for all heads at once
+    (rows = KV * bq * G); the per-head dots are a static python loop (KV
+    is small). The pages' dequant scales are SMEM scalars (``ks_ref[b,
+    e]``), gathered per row outside the kernel so their size follows the
+    page table, not the pool."""
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb:]
+    b, qb, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n = live_extent(qs_ref[b], len_ref[b], qb, ps=ps, bq=bq,
+                    num_table_pages=np_)
+    live_blocks = (n + ppb - 1) // ppb
+
+    @pl.when(i == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                     # (bq, KV, G, hd)
-    k = _dequant(k_ref[0], ks_ref[b, p], bits=bits,
-                 head_dim=head_dim)                      # (ps, KV, hd)
-    v = _dequant(v_ref[0], vs_ref[b, p], bits=bits,
-                 head_dim=head_dim)                      # (ps, KV, hd)
+    @pl.when(i < live_blocks)
+    def _live():
+        q = q_ref[0].astype(jnp.float32)                 # (bq, KV, G, hd)
+        # each slot holds the page of _entry(...) (a live page): dequantize
+        # it by that entry's scale; slots past the extent fall out of the
+        # mask below, and their values are real, finite page data
+        es = [_entry(i, j, n, ppb) for j in range(ppb)]
+        k = jnp.concatenate(
+            [_dequant(k_refs[j][0], ks_ref[b, es[j]], bits=bits,
+                      head_dim=head_dim) for j in range(ppb)],
+            axis=0)                                      # (ppb*ps, KV, hd)
+        v = jnp.concatenate(
+            [_dequant(v_refs[j][0], vs_ref[b, es[j]], bits=bits,
+                      head_dim=head_dim) for j in range(ppb)],
+            axis=0)                                      # (ppb*ps, KV, hd)
 
-    # one causal/length mask, shared by every kv head: flattened row r is
-    # chunk query r // G, at absolute position q_start[b] + qb*bq + r // G
-    pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    qrow = jax.lax.broadcasted_iota(jnp.int32, (bq * g, 1), 0) // g
-    q_pos = qs_ref[b] + qb * bq + qrow                   # (bq*G, 1)
-    mask = (pos <= q_pos) & (pos < len_ref[b])           # (bq*G, ps)
+        # one causal/length mask, shared by every kv head: flattened row r
+        # is chunk query r // G, at absolute position q_start[b] + qb*bq +
+        # r // G
+        pos = i * ppb * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (1, ppb * ps), 1)
+        qrow = jax.lax.broadcasted_iota(jnp.int32, (bq * g, 1), 0) // g
+        q_pos = qs_ref[b] + qb * bq + qrow               # (bq*G, 1)
+        # keys the row holds: kv_len, cut at the table's end where a padded
+        # chunk runs past it (the ragged last block's spare slots sit there)
+        kv_end = jnp.minimum(len_ref[b], np_ * ps)
+        mask = (pos <= q_pos) & (pos < kv_end)           # (bq*G, ppb*ps)
 
-    scores = []
-    for h in range(kv):                                  # static unroll
-        qh = (q[:, h].reshape(bq * g, head_dim) * sm_scale)
-        s = jnp.dot(qh, k[:, h].T, precision=_F32,
-                    preferred_element_type=jnp.float32)  # (bq*G, ps)
-        scores.append(jnp.where(mask, s, NEG_INF))
-    s = jnp.concatenate(scores, axis=0)                  # (KV*bq*G, ps)
+        scores = []
+        for h in range(kv):                              # static unroll
+            qh = (q[:, h].reshape(bq * g, head_dim) * sm_scale)
+            s = jnp.dot(qh, k[:, h].T, precision=_F32,
+                        preferred_element_type=jnp.float32)
+            scores.append(jnp.where(mask, s, NEG_INF))
+        s = jnp.concatenate(scores, axis=0)              # (KV*bq*G, ppb*ps)
 
-    m_prev = m_ref[...]                                  # (KV*bq*G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    pexp = jnp.exp(s - m_new)                            # (KV*bq*G, ps)
-    corr = jnp.exp(m_prev - m_new)                       # (KV*bq*G, 1)
-    l_ref[...] = l_ref[...] * corr + pexp.sum(axis=1, keepdims=True)
-    upd = jnp.concatenate(
-        [jnp.dot(pexp[h * bq * g:(h + 1) * bq * g], v[:, h],
-                 precision=_F32, preferred_element_type=jnp.float32)
-         for h in range(kv)],
-        axis=0)                                          # (KV*bq*G, hd)
-    acc_ref[...] = acc_ref[...] * corr + upd
-    m_ref[...] = m_new
+        m_prev = m_ref[...]                              # (KV*bq*G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)                   # (KV*bq*G, 1)
+        l_ref[...] = l_ref[...] * corr + pexp.sum(axis=1, keepdims=True)
+        upd = jnp.concatenate(
+            [jnp.dot(pexp[h * bq * g:(h + 1) * bq * g], v[:, h],
+                     precision=_F32, preferred_element_type=jnp.float32)
+             for h in range(kv)],
+            axis=0)                                      # (KV*bq*G, hd)
+        acc_ref[...] = acc_ref[...] * corr + upd
+        m_ref[...] = m_new
 
-    @pl.when(p == np_ - 1)
-    def _fin():
-        o = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-             ).reshape(kv, bq, g, head_dim)
-        o_ref[0] = jnp.moveaxis(o, 0, 1).astype(o_ref.dtype)
+        @pl.when(i == live_blocks - 1)
+        def _fin():
+            o = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                 ).reshape(kv, bq, g, head_dim)
+            o_ref[0] = jnp.moveaxis(o, 0, 1).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bits", "block_q", "interpret"))
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                              page_table, q_start, kv_len, *, bits: int = 8,
-                             block_q: int = 8, interpret: bool = False):
+                             block_q: int = BLOCK_Q,
+                             interpret: bool = False):
     """Variable-length chunk attention over a paged quantized KV pool.
 
     q: (B, S, H, hd) float — S chunk queries per sequence (S == 1: decode).
     k_pages/v_pages: (P, ps, KV, hdw) — int8 grid (bits=8), int32 lane-packed
         words with hdw = hd * bits / 32 (bits < 8), or float (bits=0).
     k_scale/v_scale: (P,) f32 per-page dequant scales (value = grid * scale).
-    page_table: (B, NP) int32 pool-page ids; unused entries must reference a
-        valid page (use the scratch page 0).
+    page_table: (B, NP) int32 pool-page ids; the kernel reads only each
+        row's live extent (module docstring), but unused entries must still
+        reference a valid page (the scratch page 0) for the gather path.
     q_start: (B,) int32 absolute position of chunk token 0 per row (== the
         row's cache write offset); query i sits at ``q_start + i`` and
         attends keys causally up to that position.
     kv_len: (B,) int32 valid history length per row INCLUDING the chunk's
-        real tokens (>= 1). For padded chunks, query rows past the valid
-        tail produce garbage outputs that no caller reads.
+        real tokens (>= 1; it may run past the table, NP * ps, where a
+        padded chunk ends past it). For padded chunks, query rows past the
+        valid tail produce garbage outputs that no caller reads.
     bits must match the page container. Returns (B, S, H, hd) float32.
 
-    Every block's last two dims are whole array dims (``(KV, hdw)`` for
-    pages, ``(G, hd)`` for queries), which is what the TPU compiler's
-    tiling rule accepts at any head count.
+    The query and output blocks' last two dims are whole array dims
+    (``(G, hd)``), and each page copy moves a whole ``(ps, KV, hdw)`` page,
+    which is what the TPU compiler's tiling rule accepts at any head count.
     """
     B, S, H, hd = q.shape
     P, ps, KV, hdw = k_pages.shape
@@ -157,6 +257,7 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
     bq = max(1, min(block_q, S))
     nq = -(-S // bq)
     sp = nq * bq
+    ppb = pages_per_block(ps, NP, kv_heads=KV, words=hdw, bits=bits)
     sm_scale = float(1.0 / np.sqrt(hd))
     pt = jnp.asarray(page_table, jnp.int32)
     qs = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32).reshape(-1), (B,))
@@ -170,16 +271,20 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
         qb_in = jnp.pad(qb_in, ((0, 0), (0, sp - S), (0, 0), (0, 0),
                                 (0, 0)))
     # scalar operands: page_table, q_start, kv_len, k/v page scales
-    page_map = lambda b, qb, p, pt, qs, ln, ks, vs: (pt[b, p], 0, 0, 0)
-    row_map = lambda b, qb, p, pt, qs, ln, ks, vs: (b, qb, 0, 0, 0)
+    row_map = lambda b, qb, i, pt, qs, ln, ks, vs: (b, qb, 0, 0, 0)
+
+    def page_spec(j):
+        def page_map(b, qb, i, pt, qs, ln, ks, vs):
+            n = live_extent(qs[b], ln[b], qb, ps=ps, bq=bq,
+                            num_table_pages=NP)
+            return (pt[b, _entry(i, j, n, ppb)], 0, 0, 0)
+        return pl.BlockSpec((1, ps, KV, hdw), page_map)
+
+    pages = [page_spec(j) for j in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, nq, NP),
-        in_specs=[
-            pl.BlockSpec((1, bq, KV, G, hd), row_map),
-            pl.BlockSpec((1, ps, KV, hdw), page_map),
-            pl.BlockSpec((1, ps, KV, hdw), page_map),
-        ],
+        grid=(B, nq, -(-NP // ppb)),
+        in_specs=[pl.BlockSpec((1, bq, KV, G, hd), row_map)] + pages + pages,
         out_specs=pl.BlockSpec((1, bq, KV, G, hd), row_map),
         scratch_shapes=[
             pltpu.VMEM((KV * bq * G, 1), jnp.float32),    # m
@@ -188,13 +293,14 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, np_=NP, ps=ps, bq=bq, kv=KV, g=G,
-                          bits=bits, head_dim=hd, sm_scale=sm_scale),
+        functools.partial(_chunk_kernel, ppb=ppb, ps=ps, bq=bq, np_=NP,
+                          kv=KV, g=G, bits=bits, head_dim=hd,
+                          sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, sp, KV, G, hd), jnp.float32),
         interpret=interpret,
         name="paged_kv_attention",
-    )(pt, qs, lens, ks, vs, qb_in, k_pages, v_pages)
+    )(pt, qs, lens, ks, vs, qb_in, *[k_pages] * ppb, *[v_pages] * ppb)
     return out[:, :S].reshape(B, S, H, hd)
 
 

@@ -851,9 +851,24 @@ class BatchedServer:
                     self.caches, pts_dev)
             self.prefill_forwards += 1
             self.program_launches += 1
+            self._count_attn_pages(starts, starts + bucket, bucket)
             for r, job in enumerate(rows):
                 job.done += int(valids[r])
                 self.pos[job.slot] = job.start + job.done
+
+    def _count_attn_pages(self, q_start, kv_len, seq_len: int):
+        """One program call's paged-kernel walk on the registry (each of
+        its layers walks the same table): ``serve.attn_pages_live``, the
+        table entries the kernel fetches, and ``serve.attn_pages_table``,
+        those a walk of every entry would visit."""
+        if self.attn_impl != "pallas":
+            return
+        from ..kernels.paged_kv_attention import page_walk
+        walked, table = page_walk(q_start, kv_len, seq_len=seq_len,
+                                  num_table_pages=self.np_max,
+                                  page_size=self.page_size)
+        self.metrics.counter("serve.attn_pages_live").inc(walked)
+        self.metrics.counter("serve.attn_pages_table").inc(table)
 
     def _finish_job(self, job: _PrefillJob):
         """Seal a prefilled slot: clock at the last prompt token (which the
@@ -1908,6 +1923,7 @@ class ServeLoop:
                                 srv.caches, pt)
                     srv.program_launches += 1
                     srv.cycles += 1
+                    srv._count_attn_pages(srv.pos, srv.pos + 1, 1)
                     nxt.copy_to_host_async()
                     fetches.append((nxt, tuple(srv.slots)))
                     # idle slots hold their token (keeps runs reproducible

@@ -284,6 +284,111 @@ def test_paged_decode_is_chunk_special_case():
     np.testing.assert_array_equal(np.asarray(d), np.asarray(c[:, 0]))
 
 
+def _live_extent_pool(rng, lens, np_, ps, kv, hd, bits):
+    """A pool and (B, NP) table in the serving layout: each row's entries
+    below ``ceil(kv_len / ps)`` hold its own pages, every entry past that
+    references the scratch page 0. Returns the pool arrays, the table and
+    the ids of the pages no live extent addresses (page 0 among them)."""
+    B = len(lens)
+    extents = -(-np.asarray(lens) // ps)
+    kq, vq, ks, vs, _ = _mk_fragmented_pool(rng, B, np_, ps, kv, hd, bits)
+    ids = rng.permutation(np.arange(1, kq.shape[0]))[:int(extents.sum())]
+    pt = np.zeros((B, np_), np.int32)
+    for r, live in enumerate(np.split(ids, np.cumsum(extents)[:-1])):
+        pt[r, :len(live)] = live
+    dead = np.setdiff1d(np.arange(kq.shape[0]), ids)
+    return kq, vq, ks, vs, pt, dead
+
+
+@pytest.mark.parametrize("bits", [8, 4, 0], ids=["int8", "int4", "fp"])
+@pytest.mark.parametrize("s", [1, 8], ids=["decode", "chunk8"])
+@pytest.mark.parametrize("g", [1, 8], ids=["g1", "g8"])
+def test_paged_kernel_never_reads_dead_pages(bits, s, g):
+    """Wide tables (NP far past the live pages) with an empty-slot row at
+    kv_len 1 beside long rows: every pool page outside the rows' live
+    extents, the scratch page 0 included, is NaN (values and scale), and
+    the kernel must still equal the oracle on the same pool with those
+    pages zeroed — a walk past the extent would put NaN in the PV dot."""
+    rng = np.random.default_rng(s * 10 + g + bits)
+    kv, hd, ps, np_ = 2, 16, 8, 40
+    # q_start > 0 on every row of the chunk; row 0 is an empty slot at decode
+    starts = np.asarray([0 if s == 1 else 3, 150, 37], np.int32)
+    lens = starts + s
+    kq, vq, ks, vs, pt, dead = _live_extent_pool(rng, lens, np_, ps, kv, hd,
+                                                 bits)
+    q = jnp.asarray(rng.normal(size=(3, s, kv * g, hd)), jnp.float32)
+    zero = lambda x: x.at[dead].set(0)
+    expect = ref.paged_kv_attention_chunk_ref(
+        q, zero(kq), zero(vq), ks, vs, pt, starts, lens, bits=bits)
+    if bits == 0:
+        kq, vq = kq.at[dead].set(jnp.nan), vq.at[dead].set(jnp.nan)
+    ks, vs = ks.at[dead].set(jnp.nan), vs.at[dead].set(jnp.nan)
+    out = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, jnp.asarray(pt),
+                                       jnp.asarray(starts),
+                                       jnp.asarray(lens), bits=bits)
+    np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("np_", [20, 32], ids=["np20", "np32"])
+@pytest.mark.parametrize("edge", [-1, 0, 1, "full", "past"],
+                         ids=["block-1", "block", "block+1", "full", "past"])
+@pytest.mark.parametrize("s", [1, 8], ids=["decode", "chunk8"])
+def test_paged_kernel_ragged_blocks(np_, edge, s):
+    """kv_len at a page-block edge (ppb * ps keys, +-1) and at the full
+    table, with NP a multiple of the derived block (32 entries, blocks of
+    16) and not one (20: a ragged last block of 4), against the oracle;
+    every container, rotating with the case. ``past``: rows whose chunk
+    ends past the table (kv_len = q_start + 8 > NP * ps), as a decode row
+    at the table's end inside a fused 8-token chunk, or a padded prefill
+    tail, puts them: the walk stops at the table and the output is still
+    written."""
+    from repro.kernels.paged_kv_attention import pages_per_block
+    kv, g, hd, ps = 2, 2, 16, 8
+    bits = {-1: 8, 0: 4, 1: 0, "full": 8, "past": 4}[edge]
+    hdw = hd * bits // 32 if 0 < bits < 8 else hd
+    ppb = pages_per_block(ps, np_, kv_heads=kv, words=hdw, bits=bits)
+    assert ppb == 16
+    if edge == "past":
+        starts = np_ * ps - np.asarray([2, 5], np.int32)
+        lens = starts + 8
+    else:
+        n = np_ * ps if edge == "full" else ppb * ps + edge
+        lens = np.asarray([n, max(s, n - 37)], np.int32)
+        starts = lens - s
+    rng = np.random.default_rng(np_ + s + int(lens[0]))
+    kq, vq, ks, vs, pt = _mk_fragmented_pool(rng, 2, np_, ps, kv, hd, bits)
+    q = jnp.asarray(rng.normal(size=(2, s, kv * g, hd)), jnp.float32)
+    out = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, jnp.asarray(pt),
+                                       jnp.asarray(starts),
+                                       jnp.asarray(lens), bits=bits)
+    expect = ref.paged_kv_attention_chunk_ref(q, kq, vq, ks, vs, pt, starts,
+                                              lens, bits=bits)
+    np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
+
+
+def test_page_walk_counts_live_extents():
+    """The serving loop's host-side count of the kernel's walk: each query
+    block's live extent, against a brute-force count of the table entries
+    that block's queries can see (a chunk ending past the table sees all of
+    them); the table side is every entry a block."""
+    from repro.kernels.paged_kv_attention import page_walk
+    ps, np_, S, bq = 16, 12, 20, 8
+    starts = np.asarray([0, 5, 100, 170, 188], np.int32)
+    lens = starts + S - np.asarray([0, 3, 0, 9, 0])
+    want = 0
+    for st, ln in zip(starts, lens):
+        for qb in range(-(-S // bq)):
+            last_q = st + min(S, (qb + 1) * bq) - 1      # causal bound
+            want += min(-(-min(int(ln), last_q + 1) // ps), np_)
+    walked, table = page_walk(starts, lens, seq_len=S, num_table_pages=np_,
+                              page_size=ps, block_q=bq)
+    assert table == 5 * 3 * np_
+    assert walked == want
+    dec = page_walk(starts, starts + 1, seq_len=1, num_table_pages=np_,
+                    page_size=ps)
+    assert dec == (int((starts // ps + 1).sum()), 5 * np_)
+
+
 def test_kv_attention_masks_tail():
     """Entries beyond kv_len must not affect the output."""
     key = jax.random.PRNGKey(3)

@@ -602,6 +602,12 @@ def test_pallas_attn_impl_serving_smoke(smoke_model):
                      for x, y in zip(out_a, out_b)])
     assert all(r.done for r in out_b)
     assert agree >= 0.9, agree
+    # the kernel's walk, per program call: short rows in a 4-entry table
+    walk = lambda srv: [srv.metrics.counter(f"serve.attn_pages_{k}").value
+                        for k in ("live", "table")]
+    live, table = walk(b)
+    assert 0 < live < table, (live, table)
+    assert walk(a) == [0, 0]            # the gather path runs no kernel
 
 
 @pytest.mark.parametrize("kv_bits", [0, 8, 4])

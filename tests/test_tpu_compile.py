@@ -3,9 +3,10 @@
 Interpret mode (every other kernel test) never meets the TPU compiler's
 tiling and memory rules; this file asks that compiler, for a chip that is
 described, not attached, at qwen2-72b widths (hd 128, ps 16; KV 8 heads on
-one chip, 2 per chip under tp=4). Nothing runs, so these are compile checks
-only. The topology is described inside a fixture: only the worker that runs
-this file loads the TPU library.
+one chip, 2 per chip under tp=4) and at the deepseek-7b cells' widths (32
+MHA heads, 32 slots, a 256-entry table). Nothing runs, so these are
+compile checks only. The topology is described inside a fixture: only the
+worker that runs this file loads the TPU library.
 """
 import pytest
 
@@ -46,19 +47,19 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(sharding, *, kv, s, bits, num_pages):
+def _compile(sharding, *, kv, s, bits, num_pages, b=B, g=G, np_=NP):
     hdw = HD * bits // 32 if 0 < bits < 8 else HD
     pdt = {8: jnp.int8, 4: jnp.int32, 0: jnp.bfloat16}[bits]
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
-    args = (sds((B, s, kv * G, HD), jnp.bfloat16),
+    args = (sds((b, s, kv * g, HD), jnp.bfloat16),
             sds((num_pages, PS, kv, hdw), pdt),
             sds((num_pages, PS, kv, hdw), pdt),
             sds((num_pages,), jnp.float32), sds((num_pages,), jnp.float32),
-            sds((B, NP), jnp.int32), sds((B,), jnp.int32),
-            sds((B,), jnp.int32))
+            sds((b, np_), jnp.int32), sds((b,), jnp.int32),
+            sds((b,), jnp.int32))
     fn = jax.jit(lambda *a: paged_kv_attention_chunk(*a, bits=bits,
                                                      interpret=False))
     return fn.lower(*args).compile()
@@ -71,6 +72,19 @@ def test_paged_kernel_compiles_for_v5e(one_chip, no_compile_cache, kv, s,
                                        bits):
     compiled = _compile(one_chip, kv=kv, s=s, bits=bits,
                         num_pages=1 + B * NP)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("bits", [8, 4, 0], ids=["int8", "int4", "fp"])
+@pytest.mark.parametrize("s", [1, 128], ids=["decode", "prefill128"])
+def test_paged_kernel_compiles_for_v5e_kv32(one_chip, no_compile_cache, s,
+                                            bits):
+    """The deepseek-7b cells' shapes: MHA (32 KV heads, G 1), 32 slots,
+    a 256-entry table (max_len 4096), a 5120-page pool, decode and the
+    128-token prefill bucket: the page-block copies at the widths the
+    cells run."""
+    compiled = _compile(one_chip, kv=32, s=s, bits=bits, num_pages=5121,
+                        b=32, g=1, np_=256)
     assert "tpu_custom_call" in compiled.as_text()
 
 
