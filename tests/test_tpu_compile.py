@@ -3,8 +3,9 @@
 Interpret mode (every other kernel test) never meets the TPU compiler's
 tiling and memory rules; this file asks that compiler, for a chip that is
 described, not attached, at qwen2-72b widths (hd 128, ps 16; KV 8 heads on
-one chip, 2 per chip under tp=4) and at the deepseek-7b cells' widths (32
-MHA heads, 32 slots, a 256-entry table). Nothing runs, so these are
+one chip, 2 per chip under tp=4), at the deepseek-7b cells' widths (32
+MHA heads, 32 slots, a 256-entry table) and at the qwen2-72b-pp16 cell's
+(8 KV heads, G 8, 32 slots, a 224-entry table). Nothing runs, so these are
 compile checks only. The topology is described inside a fixture: only the
 worker that runs this file loads the TPU library.
 """
@@ -85,6 +86,17 @@ def test_paged_kernel_compiles_for_v5e_kv32(one_chip, no_compile_cache, s,
     cells run."""
     compiled = _compile(one_chip, kv=32, s=s, bits=bits, num_pages=5121,
                         b=32, g=1, np_=256)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("s", [1, 128], ids=["decode", "prefill128"])
+def test_paged_kernel_compiles_for_v5e_gqa_pp16(one_chip, no_compile_cache,
+                                                s):
+    """The qwen2-72b-pp16 cell's shapes: GQA (8 KV heads, G 8, so 8 query
+    rows a KV head at decode and 1024 at a 128-token chunk), 32 slots, a
+    224-entry table (max_len 3584), a 7169-page int8 pool."""
+    compiled = _compile(one_chip, kv=8, s=s, bits=8, num_pages=7169,
+                        b=32, g=8, np_=224)
     assert "tpu_custom_call" in compiled.as_text()
 
 
