@@ -327,7 +327,7 @@ def _pack_grid(q, bits):
 
 def paged_update(pool, k_new, v_new, page_table, pos, *, page_size: int,
                  container: str = "int8", int_bits=None, frac_bits=None,
-                 valid_len=None, scale_mode: str = "static"):
+                 valid_len=None, scale_mode: str = "static", layer=None):
     """Append S new tokens per sequence to the paged pool.
 
     k_new/v_new: (B, S, KV, hd) float; page_table: (B, NP) int32;
@@ -352,11 +352,18 @@ def paged_update(pool, k_new, v_new, page_table, pos, *, page_size: int,
       (gather -> rescale -> scatter of just the touched pages), so earlier
       tokens stay correct under the new scale.
 
+    ``layer`` (a scalar, possibly traced) addresses one layer of a STACKED
+    pool ``(L, P, ps, KV, hdw)``: every read and write carries the index,
+    so the pool is scattered into in place and no layer is sliced out
+    (the layer scan carries the stacked pools, ``models.transformer``).
+    ``None`` takes ``pool`` as one layer's ``(P, ps, KV, hdw)`` pool.
+
     Distinct sequences must map to distinct pages (the allocator guarantees
     it; prefix-shared pages are never written by sharers), so the scatter is
     collision-free except on the shared scratch page, where any write order
     is acceptable.
     """
+    at = _layer_index(layer)
     B, S = k_new.shape[0], k_new.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -380,18 +387,18 @@ def paged_update(pool, k_new, v_new, page_table, pos, *, page_size: int,
         # which copy_pool_pages folds to unit scale).
         first = jnp.where(offsets == 0, pids, SCRATCH_PAGE)
         return {
-            "k_pages": pool["k_pages"].at[pids, offsets].set(
+            "k_pages": pool["k_pages"].at[at + (pids, offsets)].set(
                 k_new.astype(pool["k_pages"].dtype)),
-            "v_pages": pool["v_pages"].at[pids, offsets].set(
+            "v_pages": pool["v_pages"].at[at + (pids, offsets)].set(
                 v_new.astype(pool["v_pages"].dtype)),
-            "k_scale": pool["k_scale"].at[first].set(1.0),
-            "v_scale": pool["v_scale"].at[first].set(1.0),
+            "k_scale": pool["k_scale"].at[at + (first,)].set(1.0),
+            "v_scale": pool["v_scale"].at[at + (first,)].set(1.0),
         }
 
     if scale_mode == "page":
         return _paged_update_page_scale(
             pool, k_new, v_new, page_table, pos, pids, offsets, valid,
-            page_size=page_size, container=container)
+            page_size=page_size, container=container, at=at)
     if scale_mode != "static":
         raise ValueError(f"scale_mode must be 'static' or 'page', "
                          f"got {scale_mode!r}")
@@ -402,13 +409,19 @@ def paged_update(pool, k_new, v_new, page_table, pos, *, page_size: int,
         k_q, v_q = _pack_grid(k_q, 4), _pack_grid(v_q, 4)
     sc = jnp.broadcast_to(jnp.asarray(rscale, jnp.float32), pids.shape)
     return {
-        "k_pages": pool["k_pages"].at[pids, offsets].set(
+        "k_pages": pool["k_pages"].at[at + (pids, offsets)].set(
             k_q.astype(pool["k_pages"].dtype)),
-        "v_pages": pool["v_pages"].at[pids, offsets].set(
+        "v_pages": pool["v_pages"].at[at + (pids, offsets)].set(
             v_q.astype(pool["v_pages"].dtype)),
-        "k_scale": pool["k_scale"].at[pids].set(sc),
-        "v_scale": pool["v_scale"].at[pids].set(sc),
+        "k_scale": pool["k_scale"].at[at + (pids,)].set(sc),
+        "v_scale": pool["v_scale"].at[at + (pids,)].set(sc),
     }
+
+
+def _layer_index(layer):
+    """Index prefix that addresses one layer of a stacked pool (empty for
+    a single layer's pool)."""
+    return () if layer is None else (layer,)
 
 
 _SCALE_EPS = 2.0 ** -20   # floor for all-zero chunks (avoids 0-division)
@@ -416,7 +429,7 @@ _SCALE_EPS = 2.0 ** -20   # floor for all-zero chunks (avoids 0-division)
 
 def _paged_update_page_scale(pool, k_new, v_new, page_table, pos, pids,
                              offsets, valid, *, page_size: int,
-                             container: str):
+                             container: str, at=()):
     """Per-page max-abs calibrated write (``scale_mode="page"``).
 
     Touched pages form a contiguous block range per row (positions are
@@ -434,6 +447,10 @@ def _paged_update_page_scale(pool, k_new, v_new, page_table, pos, pids,
     ``BatchedServer._ensure_page`` asserts refcount 1 on the write-target
     block); static-scale mode has no such hazard because old grids are
     never rewritten.
+
+    ``at`` is the layer index prefix of a stacked pool (``paged_update``):
+    the touched pages are gathered with it before anything is scattered,
+    so the whole update stays in place.
     """
     B, S = k_new.shape[0], k_new.shape[1]
     ps, NP = page_size, page_table.shape[1]
@@ -469,14 +486,14 @@ def _paged_update_page_scale(pool, k_new, v_new, page_table, pos, pids,
                        axis=(-2, -1))           # (B, S) per-token max-abs
         amax = jnp.where(valid & in_tok, amax, 0.0)
         need = jnp.zeros((B, nb), jnp.float32).at[rows, lb].max(amax) / qmax
-        old = old_scale[pids_nb]                # (B, nb)
+        old = old_scale[at + (pids_nb,)]        # (B, nb)
         new = jnp.maximum(jnp.where(fresh, 0.0, old),
                           jnp.maximum(need, _SCALE_EPS))
         ratio = jnp.where(fresh, 1.0, old / new)
         return new, ratio
 
     def _requant_and_write(pages, scale, x_new, new_scale, ratio):
-        got = pages[pids_nb]                    # (B, nb, ps, KV, hdw)
+        got = pages[at + (pids_nb,)]            # (B, nb, ps, KV, hdw)
         if container == "int4":
             got = unpack_bits(got, 4, hd)
         re = jnp.round(got.astype(jnp.float32)
@@ -488,9 +505,9 @@ def _paged_update_page_scale(pool, k_new, v_new, page_table, pos, pids,
         if container == "int4":
             re = _pack_grid(re, 4)
             q = _pack_grid(q, 4)
-        pages = pages.at[pids_nb].set(re.astype(pages.dtype))
-        pages = pages.at[pids, offsets].set(q.astype(pages.dtype))
-        return pages, scale.at[pids_nb].set(new_scale)
+        pages = pages.at[at + (pids_nb,)].set(re.astype(pages.dtype))
+        pages = pages.at[at + (pids, offsets)].set(q.astype(pages.dtype))
+        return pages, scale.at[at + (pids_nb,)].set(new_scale)
 
     k_ns, k_ratio = _page_scales(k_new, pool["k_scale"])
     v_ns, v_ratio = _page_scales(v_new, pool["v_scale"])
@@ -503,17 +520,20 @@ def _paged_update_page_scale(pool, k_new, v_new, page_table, pos, pids,
 
 
 def paged_gather(pool, page_table, *, container: str = "int8",
-                 head_dim: Optional[int] = None, dtype=jnp.float32):
+                 head_dim: Optional[int] = None, dtype=jnp.float32,
+                 layer=None):
     """Materialize the logical dense cache view (B, NP*ps, KV, hd) in float.
 
     Gathers each sequence's pages and dequantizes with the per-page scales.
     This is the oracle/integration path — the Pallas kernel does the same
     gather page-by-page in VMEM without ever materializing the dense view.
+    ``layer`` addresses one layer of a stacked pool, as in ``paged_update``.
     """
-    kg = pool["k_pages"][page_table]      # (B, NP, ps, KV, hdw)
-    vg = pool["v_pages"][page_table]
-    ks = pool["k_scale"][page_table]      # (B, NP)
-    vs = pool["v_scale"][page_table]
+    at = _layer_index(layer)
+    kg = pool["k_pages"][at + (page_table,)]      # (B, NP, ps, KV, hdw)
+    vg = pool["v_pages"][at + (page_table,)]
+    ks = pool["k_scale"][at + (page_table,)]      # (B, NP)
+    vs = pool["v_scale"][at + (page_table,)]
     B, NP, ps, KV = kg.shape[:4]
 
     if container == "int4":

@@ -2,11 +2,11 @@
 
 The original standalone Pallas kernel was absorbed into
 ``paged_kv_attention.py``: a contiguous (B, T, KV, hd) cache is just the
-special case of a paged pool whose page table is the identity mapping
-(sequence b's page p is pool page b * NP + p) and whose per-page scales are
-all the layer's Q(I,F) scale 2^-F. Tile size ``block_t`` becomes the page
-size, so the VMEM working set and the online-softmax loop structure are
-unchanged from the old kernel.
+special case of a one-layer paged pool whose page table is the identity
+mapping (sequence b's page p is pool page b * NP + p) and whose per-page
+scales are all the layer's Q(I,F) scale 2^-F. Tile size ``block_t``
+becomes the page size, so the VMEM working set and the online-softmax loop
+structure are unchanged from the old kernel.
 """
 from __future__ import annotations
 
@@ -34,10 +34,10 @@ def kv_attention_decode(q, k_q, v_q, kv_len, *, int_bits: int,
         k_q = jnp.pad(k_q, ((0, 0), (0, pt), (0, 0), (0, 0)))
         v_q = jnp.pad(v_q, ((0, 0), (0, pt), (0, 0), (0, 0)))
     NP = k_q.shape[1] // ps
-    k_pages = k_q.reshape(B * NP, ps, KV, hd)
-    v_pages = v_q.reshape(B * NP, ps, KV, hd)
+    k_pages = k_q.reshape(1, B * NP, ps, KV, hd)
+    v_pages = v_q.reshape(1, B * NP, ps, KV, hd)
     page_table = jnp.arange(B * NP, dtype=jnp.int32).reshape(B, NP)
-    scale = jnp.full((B * NP,), 2.0 ** -frac_bits, jnp.float32)
+    scale = jnp.full((1, B * NP), 2.0 ** -frac_bits, jnp.float32)
     lens = jnp.full((B,), jnp.asarray(kv_len, jnp.int32))
     return paged_kv_attention_decode(
         q, k_pages, v_pages, scale, scale, page_table, lens, bits=8,

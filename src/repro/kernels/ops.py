@@ -66,27 +66,29 @@ def kv_attention(q, k_q, v_q, kv_len, *, int_bits: int, frac_bits: int,
 
 
 def paged_kv_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
-                       kv_len, *, bits: int = 8, interpret=None):
-    """Decode attention over a paged quantized KV pool (see
-    kernels.paged_kv_attention for shapes). bits: 8 (int8 pages), 4
-    (int32 lane-packed pages) or 0 (float pages)."""
+                       kv_len, layer=0, *, bits: int = 8, interpret=None):
+    """Decode attention over layer ``layer`` of stacked paged quantized KV
+    pools (see kernels.paged_kv_attention for shapes). bits: 8 (int8
+    pages), 4 (int32 lane-packed pages) or 0 (float pages)."""
     interpret = _default_interpret() if interpret is None else interpret
     return paged_kv_attention_decode(q, k_pages, v_pages, k_scale, v_scale,
-                                     page_table, kv_len, bits=bits,
+                                     page_table, kv_len, layer, bits=bits,
                                      interpret=interpret)
 
 
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
-                             page_table, q_start, kv_len, *, bits: int = 8,
-                             block_q: int = BLOCK_Q, interpret=None):
+                             page_table, q_start, kv_len, layer=0, *,
+                             bits: int = 8, block_q: int = BLOCK_Q,
+                             interpret=None):
     """Variable-length (S >= 1) chunk attention over a paged quantized KV
     pool — the prefill-chunk generalization of ``paged_kv_attention`` (see
     kernels.paged_kv_attention for shapes). q: (B, S, H, hd); ``q_start``
     (B,) is the absolute position of each row's first chunk query."""
     interpret = _default_interpret() if interpret is None else interpret
     return _paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
-                                     page_table, q_start, kv_len, bits=bits,
-                                     block_q=block_q, interpret=interpret)
+                                     page_table, q_start, kv_len, layer,
+                                     bits=bits, block_q=block_q,
+                                     interpret=interpret)
 
 
 __all__ = ["quant_cast", "pack", "unpack", "qmatmul", "kv_attention",

@@ -22,17 +22,22 @@ keys, at most 128 keys a block, fewer where a sub-byte container's unpack
 would outgrow VMEM; the last block may be ragged). The (m, l, acc)
 online-softmax scratch carries across a query block's page blocks.
 
-The page table, per-row chunk start positions, per-row valid lengths and
-the per-row page scales ride as **scalar-prefetch** operands
+The pool is the STACKED pool of every layer the layer scan runs, ``(L,
+P, ps, KV, hdw)``, and the kernel reads layer ``layer`` of it in place:
+the serving step never slices a layer's pool out
+(``models.transformer._segment_scan``). The page table, per-row chunk
+start positions, per-row valid lengths, the per-row page scales and the
+layer index ride as **scalar-prefetch** operands
 (`pltpu.PrefetchScalarGridSpec`). Each grid step fetches ``ppb`` whole
-pages (all KV heads each), one ``(1, ps, KV, hdw)`` block per slot: slot
-``j``'s BlockSpec index map reads ``page_table[b, e]`` for its entry ``e``,
-so the gather happens in the pipeline, which double-buffers every slot (a
-step's pages are fetched while the step before it computes). The pool is
-handed to the kernel once per slot; each slot's DMA moves one whole page,
-the one shape the TPU compiler takes for a lane-packed container too. In
-VMEM each page is unpacked (sub-byte containers), dequantized in float32 by
-its page scale, and the block is folded into the online softmax.
+pages (all KV heads each), one ``(ps, KV, hdw)`` block per slot: slot
+``j``'s BlockSpec index map reads ``(layer, page_table[b, e])`` for its
+entry ``e``, so the gather happens in the pipeline, which double-buffers
+every slot (a step's pages are fetched while the step before it
+computes). The pool is handed to the kernel once per slot; each slot's DMA
+moves one whole page, the one shape the TPU compiler takes for a
+lane-packed container too. In VMEM each page is unpacked (sub-byte
+containers), dequantized in float32 by its page scale, and the block is
+folded into the online softmax.
 
 Live extent (:func:`live_extent`): query block ``qb`` of row ``b`` can see
 the keys below ``min(kv_len[b], q_start[b] + (qb+1) * block_q)``, i.e. the
@@ -144,15 +149,16 @@ def _entry(i, j, n, ppb):
     return jnp.minimum(i * ppb + j, last)
 
 
-def _chunk_kernel(pt_ref, qs_ref, len_ref, ks_ref, vs_ref, q_ref, *refs,
-                  ppb, ps, bq, np_, kv, g, bits, head_dim, sm_scale):
+def _chunk_kernel(pt_ref, qs_ref, len_ref, ks_ref, vs_ref, ly_ref, q_ref,
+                  *refs, ppb, ps, bq, np_, kv, g, bits, head_dim, sm_scale):
     """One grid step = one query block x one block of ``ppb`` page-table
     entries (each page ``(1, ps, KV, hdw)``, all KV heads, contiguous in
     the pool layout). The softmax state is carried for all heads at once
     (rows = KV * bq * G); the per-head dots are a static python loop (KV
     is small). The pages' dequant scales are SMEM scalars (``ks_ref[b,
     e]``), gathered per row outside the kernel so their size follows the
-    page table, not the pool."""
+    page table, not the pool. ``ly_ref`` (the layer) is read only by the
+    page index maps."""
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb:]
     b, qb, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -225,15 +231,17 @@ def _chunk_kernel(pt_ref, qs_ref, len_ref, ks_ref, vs_ref, q_ref, *refs,
 @functools.partial(jax.jit,
                    static_argnames=("bits", "block_q", "interpret"))
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
-                             page_table, q_start, kv_len, *, bits: int = 8,
-                             block_q: int = BLOCK_Q,
+                             page_table, q_start, kv_len, layer=0, *,
+                             bits: int = 8, block_q: int = BLOCK_Q,
                              interpret: bool = False):
     """Variable-length chunk attention over a paged quantized KV pool.
 
     q: (B, S, H, hd) float — S chunk queries per sequence (S == 1: decode).
-    k_pages/v_pages: (P, ps, KV, hdw) — int8 grid (bits=8), int32 lane-packed
-        words with hdw = hd * bits / 32 (bits < 8), or float (bits=0).
-    k_scale/v_scale: (P,) f32 per-page dequant scales (value = grid * scale).
+    k_pages/v_pages: (L, P, ps, KV, hdw), the stacked pools of L layers —
+        int8 grid (bits=8), int32 lane-packed words with hdw = hd * bits /
+        32 (bits < 8), or float (bits=0).
+    k_scale/v_scale: (L, P) f32 per-page dequant scales (value = grid *
+        scale).
     page_table: (B, NP) int32 pool-page ids; the kernel reads only each
         row's live extent (module docstring), but unused entries must still
         reference a valid page (the scratch page 0) for the gather path.
@@ -244,6 +252,8 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
         real tokens (>= 1; it may run past the table, NP * ps, where a
         padded chunk ends past it). For padded chunks, query rows past the
         valid tail produce garbage outputs that no caller reads.
+    layer: int32 scalar (may be traced), the layer of the stacked pools the
+        chunk attends; pages and scales of other layers are never read.
     bits must match the page container. Returns (B, S, H, hd) float32.
 
     The query and output blocks' last two dims are whole array dims
@@ -251,7 +261,7 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
     which is what the TPU compiler's tiling rule accepts at any head count.
     """
     B, S, H, hd = q.shape
-    P, ps, KV, hdw = k_pages.shape
+    _, P, ps, KV, hdw = k_pages.shape
     NP = page_table.shape[1]
     G = H // KV
     bq = max(1, min(block_q, S))
@@ -262,27 +272,28 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
     pt = jnp.asarray(page_table, jnp.int32)
     qs = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32).reshape(-1), (B,))
     lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (B,))
+    ly = jnp.asarray(layer, jnp.int32).reshape(1)
     # per-row page scales (B, NP): SMEM holds what the table addresses, so
     # the scalar operands stay small however large the pool grows
-    ks = jnp.take(k_scale.astype(jnp.float32), pt, axis=0)
-    vs = jnp.take(v_scale.astype(jnp.float32), pt, axis=0)
+    ks = k_scale[ly[0], pt].astype(jnp.float32)
+    vs = v_scale[ly[0], pt].astype(jnp.float32)
     qb_in = q.reshape(B, S, KV, G, hd)
     if sp != S:
         qb_in = jnp.pad(qb_in, ((0, 0), (0, sp - S), (0, 0), (0, 0),
                                 (0, 0)))
-    # scalar operands: page_table, q_start, kv_len, k/v page scales
-    row_map = lambda b, qb, i, pt, qs, ln, ks, vs: (b, qb, 0, 0, 0)
+    # scalar operands: page_table, q_start, kv_len, k/v page scales, layer
+    row_map = lambda b, qb, i, pt, qs, ln, ks, vs, ly: (b, qb, 0, 0, 0)
 
     def page_spec(j):
-        def page_map(b, qb, i, pt, qs, ln, ks, vs):
+        def page_map(b, qb, i, pt, qs, ln, ks, vs, ly):
             n = live_extent(qs[b], ln[b], qb, ps=ps, bq=bq,
                             num_table_pages=NP)
-            return (pt[b, _entry(i, j, n, ppb)], 0, 0, 0)
-        return pl.BlockSpec((1, ps, KV, hdw), page_map)
+            return (ly[0], pt[b, _entry(i, j, n, ppb)], 0, 0, 0)
+        return pl.BlockSpec((None, 1, ps, KV, hdw), page_map)
 
     pages = [page_spec(j) for j in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(B, nq, -(-NP // ppb)),
         in_specs=[pl.BlockSpec((1, bq, KV, G, hd), row_map)] + pages + pages,
         out_specs=pl.BlockSpec((1, bq, KV, G, hd), row_map),
@@ -300,13 +311,13 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
         out_shape=jax.ShapeDtypeStruct((B, sp, KV, G, hd), jnp.float32),
         interpret=interpret,
         name="paged_kv_attention",
-    )(pt, qs, lens, ks, vs, qb_in, *[k_pages] * ppb, *[v_pages] * ppb)
+    )(pt, qs, lens, ks, vs, ly, qb_in, *[k_pages] * ppb, *[v_pages] * ppb)
     return out[:, :S].reshape(B, S, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def paged_kv_attention_decode(q, k_pages, v_pages, k_scale, v_scale,
-                              page_table, kv_len, *, bits: int = 8,
+                              page_table, kv_len, layer=0, *, bits: int = 8,
                               interpret: bool = False):
     """Decode attention over a paged quantized KV pool — the S == 1 special
     case of :func:`paged_kv_attention_chunk` (the sole query row sits at
@@ -320,5 +331,5 @@ def paged_kv_attention_decode(q, k_pages, v_pages, k_scale, v_scale,
     lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (B,))
     out = paged_kv_attention_chunk(
         q[:, None], k_pages, v_pages, k_scale, v_scale, page_table,
-        lens - 1, lens, bits=bits, block_q=1, interpret=interpret)
+        lens - 1, lens, layer, bits=bits, block_q=1, interpret=interpret)
     return out[:, 0]

@@ -84,12 +84,14 @@ def masked_decode_attention_ref(q, k, v, kv_len):
 
 
 def paged_kv_attention_ref(q, k_pages, v_pages, k_scale, v_scale, page_table,
-                           kv_len, *, bits: int = 8, head_dim=None):
-    """Oracle for the paged kernel: gather pages into the logical dense view,
-    dequantize with the per-page scales, run masked softmax attention.
+                           kv_len, layer=0, *, bits: int = 8, head_dim=None):
+    """Oracle for the paged kernel: gather layer ``layer``'s pages into the
+    logical dense view, dequantize with the per-page scales, run masked
+    softmax attention.
 
-    Shapes as in ``paged_kv_attention_decode``; supports fragmented page
-    tables and per-row kv_len (partial last pages are masked).
+    Shapes as in ``paged_kv_attention_decode`` (stacked pools); supports
+    fragmented page tables and per-row kv_len (partial last pages are
+    masked).
     """
     from ..core.paged_kv import paged_gather
     container = {0: "fp", 8: "int8", 4: "int4"}[bits]
@@ -97,32 +99,36 @@ def paged_kv_attention_ref(q, k_pages, v_pages, k_scale, v_scale, page_table,
             "k_scale": k_scale, "v_scale": v_scale}
     hd = head_dim if head_dim is not None else q.shape[-1]
     k, v = paged_gather(pool, jnp.asarray(page_table, jnp.int32),
-                        container=container, head_dim=hd)
+                        container=container, head_dim=hd, layer=layer)
     return masked_decode_attention_ref(q, k, v, kv_len)
 
 
-def make_fragmented_pool(rng, B, NP, ps, kv, hd, bits, extra_pages=3):
-    """Shared oracle-test/bench fixture: a random quantized pool plus an
+def make_fragmented_pool(rng, B, NP, ps, kv, hd, bits, extra_pages=3,
+                         layers=1):
+    """Shared oracle-test/bench fixture: a random quantized pool of
+    ``layers`` stacked layers, as the serving scan carries it, plus an
     OUT-OF-ORDER page table (non-scratch page ids shuffled across
     sequences) — the fragmentation the paged kernels must be invariant to.
-    Returns ``(k_pages, v_pages, k_scale, v_scale, page_table)`` with the
-    page table as a numpy (B, NP) int32 array (callers jnp.asarray as
-    needed). ``bits``: 8 (int8 grid), 4 (lane-packed int32), 0 (float)."""
+    Returns ``(k_pages, v_pages, k_scale, v_scale, page_table)``: pages
+    ``(layers, P, ps, KV, hdw)``, scales ``(layers, P)``, the page table as
+    a numpy (B, NP) int32 array (callers jnp.asarray as needed). ``bits``:
+    8 (int8 grid), 4 (lane-packed int32), 0 (float)."""
     from ..core.qtensor import pack_bits
     P = 1 + B * NP + extra_pages
+    shape = (layers, P, ps, kv, hd)
     if bits == 8:
-        kq = jnp.asarray(rng.integers(-128, 128, (P, ps, kv, hd)), jnp.int8)
-        vq = jnp.asarray(rng.integers(-128, 128, (P, ps, kv, hd)), jnp.int8)
+        kq = jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
+        vq = jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
     elif bits == 4:
-        kq, _ = pack_bits(jnp.asarray(rng.integers(-8, 8, (P, ps, kv, hd)),
+        kq, _ = pack_bits(jnp.asarray(rng.integers(-8, 8, shape),
                                       jnp.int32), 4)
-        vq, _ = pack_bits(jnp.asarray(rng.integers(-8, 8, (P, ps, kv, hd)),
+        vq, _ = pack_bits(jnp.asarray(rng.integers(-8, 8, shape),
                                       jnp.int32), 4)
     else:
-        kq = jnp.asarray(rng.normal(size=(P, ps, kv, hd)), jnp.float32)
-        vq = jnp.asarray(rng.normal(size=(P, ps, kv, hd)), jnp.float32)
-    ks = jnp.asarray(rng.uniform(0.005, 0.08, P), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.005, 0.08, P), jnp.float32)
+        kq = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        vq = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    ks = jnp.asarray(rng.uniform(0.005, 0.08, (layers, P)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.005, 0.08, (layers, P)), jnp.float32)
     ids = np.arange(1, P)
     rng.shuffle(ids)
     pt = ids[:B * NP].reshape(B, NP).astype(np.int32)
@@ -130,12 +136,12 @@ def make_fragmented_pool(rng, B, NP, ps, kv, hd, bits, extra_pages=3):
 
 
 def paged_kv_attention_chunk_ref(q, k_pages, v_pages, k_scale, v_scale,
-                                 page_table, q_start, kv_len, *,
+                                 page_table, q_start, kv_len, layer=0, *,
                                  bits: int = 8, head_dim=None):
-    """Oracle for the variable-length chunk kernel: gather pages into the
-    logical dense view, dequantize with the per-page scales, run softmax
-    attention with per-row causal masking against absolute query positions
-    (``q_start[b] + i``) and the row's ``kv_len``.
+    """Oracle for the variable-length chunk kernel: gather layer ``layer``'s
+    pages into the logical dense view, dequantize with the per-page scales,
+    run softmax attention with per-row causal masking against absolute
+    query positions (``q_start[b] + i``) and the row's ``kv_len``.
 
     q: (B, S, H, hd); other shapes as in ``paged_kv_attention_chunk``.
     """
@@ -146,7 +152,7 @@ def paged_kv_attention_chunk_ref(q, k_pages, v_pages, k_scale, v_scale,
     B, S, H, _ = q.shape
     hd = head_dim if head_dim is not None else q.shape[-1]
     k, v = paged_gather(pool, jnp.asarray(page_table, jnp.int32),
-                        container=container, head_dim=hd)
+                        container=container, head_dim=hd, layer=layer)
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
     qs = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32).reshape(-1), (B,))

@@ -254,19 +254,23 @@ def _upload(x):
     return jnp.asarray(np.array(x))
 
 
-def _jit_on_mesh(step, mesh):
-    """``jax.jit(step)``; with a serving mesh, ``step`` is traced under the
-    mesh's abstract context, which is how the paged attention kernel finds
-    the "model" axis to ``shard_map`` over (models.attention). The wrapper
+def _jit_on_mesh(step, mesh, caches_arg: int):
+    """``jax.jit(step)`` with the caches (positional argument
+    ``caches_arg``) donated: the step writes the KV pool in place, so the
+    input and output pools never coexist. The caller's array is deleted by
+    the call; every holder of the caches re-reads ``BatchedServer.caches``
+    after a step. With a serving mesh, ``step`` is traced under the mesh's
+    abstract context, which is how the paged attention kernel finds the
+    "model" axis to ``shard_map`` over (models.attention). The wrapper
     keeps ``step``'s name, so the module is ``jit_<name>`` either way."""
     if mesh is None:
-        return jax.jit(step)
+        return jax.jit(step, donate_argnums=caches_arg)
 
     @functools.wraps(step)
     def on_mesh(*args):
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             return step(*args)
-    return jax.jit(on_mesh)
+    return jax.jit(on_mesh, donate_argnums=caches_arg)
 
 
 class BatchedServer:
@@ -502,10 +506,11 @@ class BatchedServer:
                                           kv_scale_mode=kv_scale)
         self.decode = _jit_on_mesh(make_decode_step(cfg, quant=self.quant,
                                                     attn_impl=attn_impl),
-                                   mesh)
+                                   mesh, 3)
         self._chunk_prefill = (
             _jit_on_mesh(make_chunk_prefill_step(cfg, quant=self.quant,
-                                                 attn_impl=attn_impl), mesh)
+                                                 attn_impl=attn_impl),
+                         mesh, 4)
             if self.prefill_mode == "bucketed" else None)
         if fused not in ("on", "off"):
             raise ValueError(f"fused must be 'on' or 'off', got {fused!r}")
@@ -517,7 +522,7 @@ class BatchedServer:
         self.fused = fused == "on"
         self._fused = (_jit_on_mesh(make_fused_step(cfg, quant=self.quant,
                                                     attn_impl=attn_impl),
-                                    mesh)
+                                    mesh, 4)
                        if self.fused else None)
         if self.fused:
             # steady-state span constants: every row decodes (valid_len 1)

@@ -126,31 +126,34 @@ def _paged_container(cache) -> str:
 
 
 def paged_cache_update(cache, k_new, v_new, page_table, pos,
-                       quant: Optional[KVQuantSpec] = None, valid_len=None):
+                       quant: Optional[KVQuantSpec] = None, valid_len=None,
+                       layer=None):
     """Append S new tokens through the page table (pos scalar or (B,)).
 
     ``valid_len`` marks trailing chunk tokens as padding (their writes go to
     the scratch page) — the bucketed-prefill contract (core.paged_kv).
+    ``layer`` addresses one layer of a stacked ``(L, P, ...)`` pool.
     """
     container = _paged_container(cache)
     return paged_update(
         cache, k_new, v_new, page_table, pos,
-        page_size=cache["k_pages"].shape[1], container=container,
+        page_size=cache["k_pages"].shape[-3], container=container,
         int_bits=None if quant is None else quant.int_bits,
         frac_bits=None if quant is None else quant.frac_bits,
         valid_len=valid_len,
-        scale_mode="static" if quant is None else quant.scale_mode)
+        scale_mode="static" if quant is None else quant.scale_mode,
+        layer=layer)
 
 
-def paged_cache_view(cache, page_table, *, head_dim, dtype):
+def paged_cache_view(cache, page_table, *, head_dim, dtype, layer=None):
     """Logical dense (B, NP*ps, KV, hd) float view of a paged cache."""
     return paged_gather(cache, page_table, container=_paged_container(cache),
-                        head_dim=head_dim, dtype=dtype)
+                        head_dim=head_dim, dtype=dtype, layer=layer)
 
 
 def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
                           cfg, attn_impl: str = "gather",
-                          operand_dtype=jnp.float32):
+                          operand_dtype=jnp.float32, layer=None):
     """Unified variable-length paged attention entry point.
 
     ONE routing layer for every paged attention read — chunked prefill
@@ -169,12 +172,13 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
       the equivalence tests rely on).
 
     ``q``: (B, S, H, hd) post-RoPE queries; ``cache``: the pool dict AFTER
-    this chunk's ``paged_cache_update`` write; ``cache_pos``: scalar or (B,)
-    position of the chunk's first token. Padded chunk tails need no special
-    masking here: the causal bound of every REAL query is tighter than the
-    padding positions, and padded queries' outputs are garbage nobody reads
-    (their pool writes were scratch-redirected). Returns (B, S, H, hd) in
-    q.dtype.
+    this chunk's ``paged_cache_update`` write, a stacked ``(L, P, ...)``
+    pool read at ``layer`` or (``layer=None``) one layer's pool;
+    ``cache_pos``: scalar or (B,) position of the chunk's first token.
+    Padded chunk tails need no special masking here: the causal bound of
+    every REAL query is tighter than the padding positions, and padded
+    queries' outputs are garbage nobody reads (their pool writes were
+    scratch-redirected). Returns (B, S, H, hd) in q.dtype.
     """
     B, S, H, hd = q.shape
     base = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1),
@@ -183,39 +187,44 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
         if not cfg.causal:
             raise ValueError("the paged attention kernel masks causally; "
                              "a non-causal config needs attn_impl='gather'")
-        return _paged_kernel_attention(q, cache, page_table, base)
+        return _paged_kernel_attention(q, cache, page_table, base, layer)
     kd, vd = paged_cache_view(cache, page_table, head_dim=hd,
-                              dtype=operand_dtype)
+                              dtype=operand_dtype, layer=layer)
     return attend_chunked(q, kd, vd, positions, 0, causal=cfg.causal,
                           kv_len=base + S, chunk=cfg.attn_chunk,
                           operand_dtype=operand_dtype)
 
 
-def _paged_kernel_attention(q, cache, page_table, base):
-    """The Pallas paged kernel on the chunk ``q`` (B, S, H, hd). Under a
-    mesh context whose "model" axis divides the KV heads (tensor-parallel
-    serving: pools sharded on KV heads, queries on heads), the kernel runs
-    per shard through ``shard_map`` on that shard's heads and pages, so
-    the partitioner never gathers the pools onto one device."""
+def _paged_kernel_attention(q, cache, page_table, base, layer):
+    """The Pallas paged kernel on the chunk ``q`` (B, S, H, hd), reading
+    layer ``layer`` of the stacked pool in place (a single layer's pool,
+    ``layer=None``, is read as a stack of one). Under a mesh context whose
+    "model" axis divides the KV heads (tensor-parallel serving: pools
+    sharded on KV heads, queries on heads), the kernel runs per shard
+    through ``shard_map`` on that shard's heads and pages, so the
+    partitioner never gathers the pools onto one device."""
     from ..kernels.ops import paged_kv_attention_chunk
     S = q.shape[1]
     bits = {"int8": 8, "int4": 4, "fp": 0}[_paged_container(cache)]
+    pools = [cache[f] for f in ("k_pages", "v_pages", "k_scale", "v_scale")]
+    if layer is None:
+        pools, layer = [a[None] for a in pools], 0
 
-    def kernel(q, k_pages, v_pages, k_scale, v_scale, pt, base):
+    def kernel(q, k_pages, v_pages, k_scale, v_scale, pt, base, layer):
         return paged_kv_attention_chunk(q, k_pages, v_pages, k_scale,
                                         v_scale, pt, base, base + S,
-                                        bits=bits)
+                                        layer=layer, bits=bits)
 
-    args = (q, cache["k_pages"], cache["v_pages"], cache["k_scale"],
-            cache["v_scale"], page_table, base)
+    args = (q, *pools, page_table, base, jnp.asarray(layer, jnp.int32))
     mesh = jax.sharding.get_abstract_mesh()
     tp = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
     if tp > 1 and cache["k_pages"].shape[-2] % tp == 0:
         from jax.sharding import PartitionSpec as P
         heads = P(None, None, "model", None)
+        pool = P(None, None, None, "model", None)
         kernel = jax.shard_map(
             kernel, mesh=mesh,
-            in_specs=(heads, heads, heads, P(), P(), P(), P()),
+            in_specs=(heads, pool, pool, P(), P(), P(), P(), P()),
             out_specs=heads, check_vma=False)
     return kernel(*args).astype(q.dtype)
 
@@ -408,7 +417,8 @@ def init_gqa(key, cfg):
 def gqa_apply(params, x, positions, *, cfg, cache=None, cache_pos=None,
               kv_quant: Optional[KVQuantSpec] = None, mrope_positions=None,
               chunked: Optional[bool] = None, page_table=None,
-              attn_impl: str = "gather", kv_valid_len=None):
+              attn_impl: str = "gather", kv_valid_len=None,
+              cache_layer=None):
     """Returns (y, new_cache). ``positions``: (B, S) absolute positions.
 
     Train/prefill: cache=None -> attends within the sequence (causal per cfg),
@@ -426,6 +436,10 @@ def gqa_apply(params, x, positions, *, cfg, cache=None, cache_pos=None,
     within float tolerance).
     ``kv_valid_len`` (scalar or (B,)) marks only the first tokens of a padded
     prefill chunk as real; padded tails scatter to the scratch page.
+    ``cache_layer`` (scalar, may be traced) marks a paged ``cache`` as the
+    whole stacked ``(L, P, ...)`` pool of the layer scan and names this
+    layer's index in it: the pool is written and read in place, and the
+    whole stacked pool is returned.
     """
     B, S, D = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -460,13 +474,14 @@ def gqa_apply(params, x, positions, *, cfg, cache=None, cache_pos=None,
             raise ValueError(f"attn_impl must be 'gather' or 'pallas', "
                              f"got {attn_impl!r}")
         new_cache = paged_cache_update(cache, k, v, page_table, cache_pos,
-                                       kv_quant, valid_len=kv_valid_len)
+                                       kv_quant, valid_len=kv_valid_len,
+                                       layer=cache_layer)
         # ONE entry point for chunk prefill AND decode: the routing layer
         # picks the Pallas chunk kernel (S >= 1; per-page online softmax,
         # float-tolerance equal) or the jnp gather path (bitwise reference)
         o = route_paged_attention(q, new_cache, page_table, positions,
                                   cache_pos, cfg=cfg, attn_impl=attn_impl,
-                                  operand_dtype=odt)
+                                  operand_dtype=odt, layer=cache_layer)
     elif cache is not None:
         pos = cache_pos
         new_cache = cache_update(cache, k, v, pos, kv_quant)

@@ -207,12 +207,14 @@ def init_block(key, cfg, sig):
 def block_apply(params, x, positions, *, cfg, sig, cache=None, cache_pos=None,
                 quant: Optional[ModelQuant] = None, mrope_positions=None,
                 page_table=None, attn_impl: str = "gather",
-                kv_valid_len=None):
+                kv_valid_len=None, cache_layer=None):
     """Returns (x, new_cache, aux). ``quant`` holds per-THIS-layer scalars.
 
     ``attn_impl``/``kv_valid_len`` only affect paged GQA attention: kernel
     vs gather routing (one variable-length path for chunk prefill AND
     decode) and padded-chunk masking (see ``attention.gqa_apply``).
+    ``cache_layer`` is this layer's index in a stacked paged ``cache``
+    (``_segment_scan``), which is then updated in place and returned whole.
     """
     kind, ffn = sig
     aux = {}
@@ -241,7 +243,8 @@ def block_apply(params, x, positions, *, cfg, sig, cache=None, cache_pos=None,
                                      mrope_positions=mrope_positions,
                                      page_table=page_table,
                                      attn_impl=attn_impl,
-                                     kv_valid_len=kv_valid_len)
+                                     kv_valid_len=kv_valid_len,
+                                     cache_layer=cache_layer)
     elif kind == "mamba":
         y, new_cache = mamba_apply(params["mixer"], h, cfg=cfg, state=cache,
                                    state_quant=state_quant)
@@ -407,26 +410,40 @@ def _segment_scan(seg_params, x, positions, *, cfg, pattern, start, periods,
                   caches=None, cache_pos=None, quant=None,
                   mrope_positions=None, page_table=None,
                   attn_impl: str = "gather", kv_valid_len=None):
-    """Scan one segment. Returns (x, new_caches, aux_sums)."""
+    """Scan one segment. Returns (x, new_caches, aux_sums).
+
+    Paged KV pools (dicts with ``k_pages``, stacked ``(periods, P, ...)``)
+    ride the scan's CARRY whole, and each period writes and reads its
+    layer of them in place through its index in the segment (an ``xs``
+    operand): no layer's pool is ever sliced out or restacked, so with the
+    caches donated the step updates the pool in place. Dense caches and
+    SSM states are per-period ``xs``/``ys`` as before."""
     npos = len(pattern)
     layer_idx = start + jnp.arange(periods * npos).reshape(periods, npos)
     quant_x = (quant.layer_slice(layer_idx) if quant is not None else None)
+    caches = caches if caches is not None else (None,) * npos
+    paged = tuple(isinstance(c, dict) and "k_pages" in c for c in caches)
+    pools = tuple(c if p else None for c, p in zip(caches, paged))
+    scanned = tuple(None if p else c for c, p in zip(caches, paged))
 
     def body(carry, xs):
-        x = carry
-        seg_p, cache_p, q_p = xs
-        new_caches, auxes = [], []
+        x, pools = carry
+        seg_p, cache_p, q_p, period = xs
+        pools, new_caches, auxes = list(pools), [], []
         for pi, sig in enumerate(pattern):
             q_i = (q_p.layer_slice(pi) if q_p is not None else None)
-            c_i = cache_p[pi] if cache_p is not None else None
             x, nc, aux = block_apply(
-                seg_p[pi], x, positions, cfg=cfg, sig=sig, cache=c_i,
+                seg_p[pi], x, positions, cfg=cfg, sig=sig,
+                cache=pools[pi] if paged[pi] else cache_p[pi],
                 cache_pos=cache_pos, quant=q_i,
                 mrope_positions=mrope_positions, page_table=page_table,
-                attn_impl=attn_impl, kv_valid_len=kv_valid_len)
+                attn_impl=attn_impl, kv_valid_len=kv_valid_len,
+                cache_layer=period if paged[pi] else None)
+            if paged[pi]:
+                pools[pi], nc = nc, None
             new_caches.append(nc)
             auxes.append(aux.get("moe_lb_loss", jnp.zeros((), jnp.float32)))
-        return x, (tuple(new_caches), jnp.stack(auxes).sum())
+        return (x, tuple(pools)), (tuple(new_caches), jnp.stack(auxes).sum())
 
     body_fn = body
     if cfg.remat != "none":
@@ -434,8 +451,10 @@ def _segment_scan(seg_params, x, positions, *, cfg, pattern, start, periods,
                                  policy=jax.checkpoint_policies.nothing_saveable
                                  if cfg.remat == "full" else None)
 
-    xs = (tuple(seg_params), caches, quant_x)
-    x, (new_caches, aux_per) = jax.lax.scan(body_fn, x, xs)
+    xs = (tuple(seg_params), scanned, quant_x, jnp.arange(periods))
+    (x, pools), (new_caches, aux_per) = jax.lax.scan(body_fn, (x, pools), xs)
+    new_caches = tuple(pl if p else c
+                       for pl, c, p in zip(pools, new_caches, paged))
     return x, new_caches, aux_per.sum()
 
 

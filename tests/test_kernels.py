@@ -156,11 +156,11 @@ def test_paged_kv_attention_fragmented_vs_contiguous():
         prng.shuffle(ids)
         pt = ids.reshape(B, NP).astype(np.int32)
         P = 1 + B * NP
-        kq = np.zeros((P, ps, KV, hd), np.int8)
-        vq = np.zeros((P, ps, KV, hd), np.int8)
-        kq[pt] = logical_k
-        vq[pt] = logical_v
-        sc = np.full(P, 2.0 ** -5, np.float32)
+        kq = np.zeros((1, P, ps, KV, hd), np.int8)
+        vq = np.zeros((1, P, ps, KV, hd), np.int8)
+        kq[0, pt] = logical_k
+        vq[0, pt] = logical_v
+        sc = np.full((1, P), 2.0 ** -5, np.float32)
         outs.append(ops.paged_kv_attention(
             q, jnp.asarray(kq), jnp.asarray(vq), jnp.asarray(sc),
             jnp.asarray(sc), jnp.asarray(pt), lens, bits=8))
@@ -175,9 +175,9 @@ def test_paged_int4_matches_int8_on_same_grid():
     B, KV, G, hd, ps, NP = 1, 2, 2, 16, 8, 2
     P = 1 + B * NP
     q = jnp.asarray(rng.normal(size=(B, KV * G, hd)), jnp.float32)
-    grid_k = rng.integers(-8, 8, (P, ps, KV, hd))
-    grid_v = rng.integers(-8, 8, (P, ps, KV, hd))
-    sc = jnp.full((P,), 0.25, jnp.float32)
+    grid_k = rng.integers(-8, 8, (1, P, ps, KV, hd))
+    grid_v = rng.integers(-8, 8, (1, P, ps, KV, hd))
+    sc = jnp.full((1, P), 0.25, jnp.float32)
     pt = jnp.asarray([[2, 1]], jnp.int32)
     lens = jnp.asarray([13], jnp.int32)
     o8 = ops.paged_kv_attention(q, jnp.asarray(grid_k, jnp.int8),
@@ -284,19 +284,21 @@ def test_paged_decode_is_chunk_special_case():
     np.testing.assert_array_equal(np.asarray(d), np.asarray(c[:, 0]))
 
 
-def _live_extent_pool(rng, lens, np_, ps, kv, hd, bits):
-    """A pool and (B, NP) table in the serving layout: each row's entries
-    below ``ceil(kv_len / ps)`` hold its own pages, every entry past that
-    references the scratch page 0. Returns the pool arrays, the table and
-    the ids of the pages no live extent addresses (page 0 among them)."""
+def _live_extent_pool(rng, lens, np_, ps, kv, hd, bits, layers):
+    """A stacked pool and (B, NP) table in the serving layout: each row's
+    entries below ``ceil(kv_len / ps)`` hold its own pages, every entry
+    past that references the scratch page 0. Returns the pool arrays, the
+    table and the ids of the pages no live extent addresses (page 0 among
+    them)."""
     B = len(lens)
     extents = -(-np.asarray(lens) // ps)
-    kq, vq, ks, vs, _ = _mk_fragmented_pool(rng, B, np_, ps, kv, hd, bits)
-    ids = rng.permutation(np.arange(1, kq.shape[0]))[:int(extents.sum())]
+    kq, vq, ks, vs, _ = _mk_fragmented_pool(rng, B, np_, ps, kv, hd, bits,
+                                            layers=layers)
+    ids = rng.permutation(np.arange(1, kq.shape[1]))[:int(extents.sum())]
     pt = np.zeros((B, np_), np.int32)
     for r, live in enumerate(np.split(ids, np.cumsum(extents)[:-1])):
         pt[r, :len(live)] = live
-    dead = np.setdiff1d(np.arange(kq.shape[0]), ids)
+    dead = np.setdiff1d(np.arange(kq.shape[1]), ids)
     return kq, vq, ks, vs, pt, dead
 
 
@@ -305,27 +307,30 @@ def _live_extent_pool(rng, lens, np_, ps, kv, hd, bits):
 @pytest.mark.parametrize("g", [1, 8], ids=["g1", "g8"])
 def test_paged_kernel_never_reads_dead_pages(bits, s, g):
     """Wide tables (NP far past the live pages) with an empty-slot row at
-    kv_len 1 beside long rows: every pool page outside the rows' live
-    extents, the scratch page 0 included, is NaN (values and scale), and
-    the kernel must still equal the oracle on the same pool with those
-    pages zeroed — a walk past the extent would put NaN in the PV dot."""
+    kv_len 1 beside long rows, read at layer 1 of a 3-layer stacked pool:
+    every pool page outside the rows' live extents, the scratch page 0
+    included, is NaN (values and scale), and so is every page of layers 0
+    and 2; the kernel must still equal the oracle on the same pool with
+    those pages zeroed — a walk past the extent, or into another layer,
+    would put NaN in the PV dot."""
     rng = np.random.default_rng(s * 10 + g + bits)
     kv, hd, ps, np_ = 2, 16, 8, 40
     # q_start > 0 on every row of the chunk; row 0 is an empty slot at decode
     starts = np.asarray([0 if s == 1 else 3, 150, 37], np.int32)
     lens = starts + s
     kq, vq, ks, vs, pt, dead = _live_extent_pool(rng, lens, np_, ps, kv, hd,
-                                                 bits)
+                                                 bits, layers=3)
     q = jnp.asarray(rng.normal(size=(3, s, kv * g, hd)), jnp.float32)
-    zero = lambda x: x.at[dead].set(0)
+    zero = lambda x: x.at[:, dead].set(0)
     expect = ref.paged_kv_attention_chunk_ref(
-        q, zero(kq), zero(vq), ks, vs, pt, starts, lens, bits=bits)
+        q, zero(kq), zero(vq), ks, vs, pt, starts, lens, 1, bits=bits)
+    nan = lambda x: x.at[:, dead].set(jnp.nan).at[::2].set(jnp.nan)
     if bits == 0:
-        kq, vq = kq.at[dead].set(jnp.nan), vq.at[dead].set(jnp.nan)
-    ks, vs = ks.at[dead].set(jnp.nan), vs.at[dead].set(jnp.nan)
+        kq, vq = nan(kq), nan(vq)
+    ks, vs = nan(ks), nan(vs)
     out = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, jnp.asarray(pt),
                                        jnp.asarray(starts),
-                                       jnp.asarray(lens), bits=bits)
+                                       jnp.asarray(lens), 1, bits=bits)
     np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
 
 

@@ -269,28 +269,38 @@ def mk():
                  5, priority=5, arrive_step=4)
     mid = Request(2, rng.integers(0, cfg.vocab_size, 5).astype(np.int32),
                   6, priority=1, arrive_step=8)
-    return [low, hi, mid]
+    # shares low's first 5 prompt tokens: a hit that diverges mid-page
+    dup = Request(3, np.concatenate([low.prompt[:5], rng.integers(
+        0, cfg.vocab_size, 6).astype(np.int32)]), 5, arrive_step=12)
+    return [low, hi, mid, dup]
 
 for kv_bits in (0, 8, 4):
-    for prefix in ("off", "on"):
+    for prefix, pager_async in (("off", "off"), ("on", "off"), ("on", "on")):
         # tight pool + slots: the high-priority latecomer must preempt.
         # prefix="on" additionally routes the victim's prompt pages through
         # PREEMPTION RE-ALIASING (pinned cache nodes, no host copy), which
-        # must be just as bitwise-invisible as the host round trip.
+        # must be just as bitwise-invisible as the host round trip, and
+        # gives the late duplicate a copy-on-write hit. The step programs
+        # donate the caches, so a holder that kept a stale pool would
+        # fail here: host-tier page-out and page-in (sync and async
+        # pager), the CoW copy, and every retire and readmit of the one
+        # slot all run between steps.
         srv = BatchedServer(cfg, params, batch_size=1, max_len=48,
                             kv_bits=kv_bits, page_size=8, num_pages=4,
                             kv_offload="host", sched="slo",
-                            prefix_cache=prefix)
+                            prefix_cache=prefix, pager_async=pager_async)
         reqs = srv.run(mk())
         assert srv.preempt_count >= 1, "trace failed to trigger preemption"
         assert srv.resume_count == srv.preempt_count
         assert all(r.done and r.error is None for r in reqs)
+        assert srv.host_store.pops >= 1, "no page came back from the host"
         if prefix == "on":
             assert srv.realias_skipped >= 1, "re-aliasing never fired"
+            assert srv.prefix_cache.cow_copies >= 1, "no copy-on-write hit"
             assert srv.release_prefix_cache() == 0
         assert srv.host_store.num_pages == 0
         # uninterrupted reference: same requests, roomy pool, no preemption
-        ref = BatchedServer(cfg, params, batch_size=3, max_len=48,
+        ref = BatchedServer(cfg, params, batch_size=4, max_len=48,
                             kv_bits=kv_bits, page_size=8)
         ref_reqs = ref.run(mk())
         assert ref.preempt_count == 0
@@ -299,9 +309,11 @@ for kv_bits in (0, 8, 4):
             assert r.out == by_rid[r.rid].out, (kv_bits, prefix, r.rid,
                                                 r.out, by_rid[r.rid].out)
         n_pre = sum(r.preemptions for r in reqs)
-        print(f"kv_bits={kv_bits} prefix={prefix} bitwise-identical "
-              f"after {n_pre} preemption(s), "
-              f"{srv.realias_skipped} demotions skipped")
+        print(f"kv_bits={kv_bits} prefix={prefix} async={pager_async} "
+              f"bitwise-identical after {n_pre} preemption(s), "
+              f"{srv.realias_skipped} demotions skipped, "
+              f"{srv.pager.demotions} demoted, {srv.pager.promotions} "
+              f"promoted")
 print("PREEMPT_IDENTITY_OK")
 """
 
@@ -310,7 +322,9 @@ def test_preempt_resume_bitwise_identical():
     """A preempted-then-resumed request emits bitwise-identical tokens to an
     unpreempted run at kv-bits {0, 8, 4}: demote->promote restores the
     packed page bytes exactly and decode continues from the restored state
-    (no re-prefill).
+    (no re-prefill). With the prefix cache on, under the sync and the async
+    pager, a late request's copy-on-write hit matches too, all under the
+    step programs' donated caches.
 
     Runs in a subprocess with single-threaded XLA: multi-threaded XLA:CPU
     GEMMs are not bitwise deterministic under thread contention, and exact

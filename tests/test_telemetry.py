@@ -722,16 +722,16 @@ def test_device_programs_have_stable_names(smoke_model):
     pt = i32(B, NP)
     programs = {
         "decode_step": (make_decode_step,
-                        (i32(B), i32(B), srv.caches, pt)),
+                        (i32(B), i32(B), srv.caches, pt), 3),
         "chunk_prefill_step": (make_chunk_prefill_step,
                                (i32(B, 8), i32(B), i32(B), srv.caches,
-                                pt)),
+                                pt), 4),
         "fused_step": (make_fused_step,
                        (i32(B, 8), i32(B), i32(B), srv.caches, pt,
-                        i32(B))),
+                        i32(B)), 4),
     }
     for mesh in (None, make_serving_mesh(1)):
-        for name, (make, args) in programs.items():
-            fn = _jit_on_mesh(make(cfg, quant=srv.quant), mesh)
+        for name, (make, args, caches_arg) in programs.items():
+            fn = _jit_on_mesh(make(cfg, quant=srv.quant), mesh, caches_arg)
             head = fn.lower(srv.params, *args).as_text().split("\n", 1)[0]
             assert head.startswith(f"module @jit_{name} "), (mesh, head)
