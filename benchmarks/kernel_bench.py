@@ -80,30 +80,6 @@ def bench_quant_matmul():
     return out
 
 
-def bench_kv_attention():
-    out = {}
-    for (b, h, kv, hd, t) in [(4, 8, 2, 64, 512), (2, 16, 16, 128, 1024)]:
-        q = jax.random.normal(jax.random.PRNGKey(5), (b, h, hd))
-        k_q = jax.random.randint(jax.random.PRNGKey(6), (b, t, kv, hd), -128,
-                                 128, jnp.int32).astype(jnp.int8)
-        v_q = jax.random.randint(jax.random.PRNGKey(7), (b, t, kv, hd), -128,
-                                 128, jnp.int32).astype(jnp.int8)
-        y = ops.kv_attention(q, k_q, v_q, t - 5, int_bits=2, frac_bits=6,
-                             block_t=128)
-        yr = ref.kv_attention_ref(q, k_q, v_q, 2, 6, t - 5)
-        out[f"B{b}H{h}KV{kv}hd{hd}T{t}"] = {
-            "max_err_vs_ref": float(jnp.abs(y - yr).max()),
-            "cache_bytes_int8": int(k_q.size + v_q.size),
-            "cache_bytes_bf16": int((k_q.size + v_q.size) * 2),
-            "cache_traffic_ratio": 0.5,
-            "interpret_s": _timeit(
-                lambda q, k, v: ops.kv_attention(
-                    q, k, v, t - 5, int_bits=2, frac_bits=6, block_t=128),
-                q, k_q, v_q),
-        }
-    return out
-
-
 def bench_paged_prefill_chunk():
     """Prefill-chunk attention: the variable-length paged chunk kernel
     (interpret mode) vs the jnp gather path, S in {8, 32, 128}, fragmented
@@ -141,69 +117,11 @@ def bench_paged_prefill_chunk():
     return out
 
 
-def bench_fused_step():
-    """Ragged fused-cycle attention: ONE chunk launch where a decode row
-    (1 real query, padded into the prefill bucket S) rides alongside a
-    prefill chunk row, vs TWO separate launches (S=1 decode + S-chunk
-    prefill) — the program-count saving ``--fused on`` serving buys every
-    scheduler cycle. ``decode_pad_err`` is the gather-path delta between
-    the padded decode row's real output and the standalone S=1 launch (the
-    fused serving mode's identity contract; 0.0 under deterministic XLA),
-    and the kernel errors are the pallas interpret-mode agreement on the
-    REAL (non-padding) outputs of the same launch."""
-    out = {}
-    kv, g, hd, ps = 2, 2, 32, 16
-    for S in (8, 32):
-        for bits, cont in ((0, "fp"), (8, "int8"), (4, "int4")):
-            rng = np.random.default_rng(S * 7 + bits)
-            dec_pos = 2 * ps + 3              # decode row: 1 query at pos
-            pre_start = ps - 1                # prefill row: straddles pages
-            NP = -(-max(dec_pos + 1, pre_start + S) // ps)
-            kq, vq, ks, vs, pt = ref.make_fragmented_pool(rng, 2, NP, ps,
-                                                          kv, hd, bits)
-            q = jnp.asarray(rng.normal(size=(2, S, kv * g, hd)), jnp.float32)
-            qs = jnp.asarray(np.array([dec_pos, pre_start], np.int32))
-            lens = jnp.asarray(np.array([dec_pos + 1, pre_start + S],
-                                        np.int32))
-            ref_fn = jax.jit(functools.partial(
-                ref.paged_kv_attention_chunk_ref, bits=bits))
-            fused = ref_fn(q, kq, vq, ks, vs, pt, qs, lens)
-            dec = ref_fn(q[:1, :1], kq, vq, ks, vs, pt[:1], qs[:1],
-                         lens[:1])
-            pre = ref_fn(q[1:], kq, vq, ks, vs, pt[1:], qs[1:], lens[1:])
-            y = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, pt, qs,
-                                             lens, bits=bits)
-
-            def two_launches(q, kq, vq, ks, vs, pt, qs, lens):
-                return (ref_fn(q[:1, :1], kq, vq, ks, vs, pt[:1], qs[:1],
-                               lens[:1]),
-                        ref_fn(q[1:], kq, vq, ks, vs, pt[1:], qs[1:],
-                               lens[1:]))
-
-            out[f"S{S}-{cont}"] = {
-                "decode_pad_err": float(
-                    jnp.abs(fused[0, 0] - dec[0, 0]).max()),
-                "prefill_row_err": float(jnp.abs(fused[1] - pre[0]).max()),
-                "max_err_vs_gather": float(jnp.maximum(
-                    jnp.abs(y[0, 0] - fused[0, 0]).max(),
-                    jnp.abs(y[1] - fused[1]).max())),
-                "launches_per_cycle_fused": 1,
-                "launches_per_cycle_separate": 2,
-                "fused_1launch_s": _timeit(ref_fn, q, kq, vq, ks, vs, pt,
-                                           qs, lens, reps=1),
-                "separate_2launch_s": _timeit(two_launches, q, kq, vq, ks,
-                                              vs, pt, qs, lens, reps=1),
-            }
-    return out
-
-
 _STAGES = {
     "quant_cast": bench_quant_cast,
     "pack": bench_pack,
     "quant_matmul": bench_quant_matmul,
-    "kv_attention": bench_kv_attention,
     "paged_prefill_chunk": bench_paged_prefill_chunk,
-    "fused_step": bench_fused_step,
 }
 
 

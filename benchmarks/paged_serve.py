@@ -51,25 +51,13 @@ round trip vs adapt-off, the lm_precision accuracy gate (>=0.9 token
 agreement vs the byte-exact adapt-off reference, zero violations), and
 pool/host/tier leak checks — the CI adapt-smoke gate.
 
-A fifth, **ragged fused-step workload** (``run_ragged`` / ``--workload
-ragged``) serves a SATURATED shared-prefix backlog (every request queued at
-t=0, queue depth >> batch) three ways: sequential admission, batched
-admission with prefix-aware wave dedupe (--prefill-batch x --prefix-cache
-composition), and ``--fused on`` (ONE ragged variable-length program per
-scheduler cycle). It gates (RAISES) on fused running strictly fewer total
-program launches than the separate-program path, exactly one launch per
-cycle, wave dedupe running strictly fewer prefill forwards than sequential
-admission, >=0.9 token agreement for both, and pool leak checks — the CI
-ragged-smoke gate.
-
 Results land in results/paged_serve.json (+ results/prefix_serve.json,
-results/overcommit_serve.json, results/adapt_serve.json,
-results/ragged_serve.json) AND append a trajectory point to the repo-root
+results/overcommit_serve.json, results/adapt_serve.json) AND append a trajectory point to the repo-root
 BENCH_serve.json so the perf trend is tracked across PRs.
 
 Run:  PYTHONPATH=src python -m benchmarks.paged_serve [--arch qwen2-72b]
       [--page-size 16] [--requests 12] [--fast]
-      [--workload all|mixed|prefix|overcommit|adapt|ragged]
+      [--workload all|mixed|prefix|overcommit|adapt]
 (--fast = CI smoke: tiny trace, one bench iteration per config.)
 """
 from __future__ import annotations
@@ -756,132 +744,6 @@ def run_adapt(*, arch="qwen2-72b", verbose=True, fast=False):
     return res
 
 
-def run_ragged(*, arch="qwen2-72b", requests=12, batch=4, verbose=True,
-               fast=False):
-    """Ragged fused-step workload: a SATURATED shared-prefix backlog (all
-    requests queued at t=0, queue depth >> batch) served three ways —
-
-      seq — sequential admission (--prefill-batch 1), separate prefill +
-            decode programs: the program-count reference
-      bat — batched admission (auto cap = batch size) + prefix cache: the
-            prefix-aware wave dedupe composition (--prefill-batch no longer
-            falls back to sequential under --prefix-cache)
-      fus — ``--fused on``: ONE ragged variable-length program per
-            scheduler cycle (decode rows S=1 riding in prefill buckets)
-
-    Program-count economics only favor fused under saturation (steady
-    decode occupancy + admission folded into decode cycles); drain-phase
-    desync can eat the savings on thin traces, which is why this trace
-    keeps the queue deep.
-
-    GATES (RAISES — the CI ragged-smoke step): fused must run strictly
-    fewer total program launches than the separate-program path at exactly
-    one launch per cycle, wave dedupe must run strictly fewer prefill
-    forwards than sequential admission, both must hold >=0.9 token
-    agreement vs seq (bitwise identity is asserted separately in the
-    single-threaded-XLA subprocess test), and the pool must end leak-free.
-    """
-    if fast:
-        requests = 8
-    cfg = get_smoke_config(arch)
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    sys_len, max_new, max_len, page_size = 18, 12 if fast else 16, 64, 8
-    per_slot = -(-(sys_len + 8 + max_new) // page_size)
-    num_pages = 1 + batch * per_slot + 10   # headroom for retained prefixes
-
-    def mk():
-        return mk_prefix_requests(cfg.vocab_size, requests, sys_len,
-                                  max_new, seed=5)
-
-    def serve(name, **kw):
-        srv = BatchedServer(cfg, params, batch_size=batch, max_len=max_len,
-                            page_size=page_size, num_pages=num_pages,
-                            kv_bits=8, prefill="bucketed",
-                            prefill_bucket=16, prefix_cache="on",
-                            metrics="on", **kw)
-        t0 = time.time()
-        reqs = srv.run(mk())
-        dt = time.time() - t0
-        assert all(r.done for r in reqs), f"{name}: unfinished requests"
-        srv.prefix_cache.clear()
-        if srv.allocator.num_free != srv.allocator.num_usable:
-            raise RuntimeError(f"ragged bench leaked pages in {name} mode")
-        return srv, reqs, dt
-
-    seq, reqs_seq, t_seq = serve("seq", prefill_batch=1, fused="off")
-    bat, reqs_bat, _ = serve("bat", prefill_batch=batch, fused="off")
-    fus, reqs_fus, t_fus = serve("fus", fused="on")
-
-    def agreement(a_reqs, b_reqs):
-        return float(np.mean([np.mean(np.asarray(a.out) == np.asarray(b.out))
-                              for a, b in zip(a_reqs, b_reqs)]))
-
-    agree_fus = agreement(reqs_seq, reqs_fus)
-    agree_bat = agreement(reqs_seq, reqs_bat)
-    if min(agree_fus, agree_bat) < 0.9:
-        raise RuntimeError(f"ragged modes broke decode: fused {agree_fus:.1%}"
-                           f" / batched {agree_bat:.1%} token agreement")
-    if fus.program_launches != fus.cycles:
-        raise RuntimeError(
-            f"fused serving launched {fus.program_launches} programs over "
-            f"{fus.cycles} cycles; the contract is exactly one per cycle")
-    if fus.program_launches >= seq.program_launches:
-        raise RuntimeError(
-            f"fused serving failed to reduce total programs on the "
-            f"saturated trace: {seq.program_launches} separate vs "
-            f"{fus.program_launches} fused")
-    if bat.prefill_forwards >= seq.prefill_forwards:
-        raise RuntimeError(
-            f"prefix-aware wave dedupe failed to reduce prefill forwards: "
-            f"{seq.prefill_forwards} sequential vs "
-            f"{bat.prefill_forwards} batched under the prefix cache")
-    # SLO reduction from the fused run's lifecycle trace + Chrome artifact;
-    # the registry double-checks the one-launch-per-cycle contract through
-    # the same counters the gate above read via legacy attributes
-    slo = fus.tracer.slo_summary()
-    assert (fus.metrics.counter("serve.program_launches").value
-            == fus.metrics.counter("serve.cycles").value)
-    trace_path = _export_trace(fus,
-                               os.path.join(RESULTS, "trace_ragged.json"))
-    res = {
-        "requests": requests, "batch": batch, "sys_len": sys_len,
-        "max_new": max_new,
-        "programs_separate": seq.program_launches,
-        "programs_fused": fus.program_launches,
-        "cycles_fused": fus.cycles,
-        "program_reduction": seq.program_launches / fus.program_launches,
-        "decode_steps_separate": seq.decode_steps,
-        "decode_steps_fused": fus.decode_steps,
-        "prefill_forwards_sequential": seq.prefill_forwards,
-        "prefill_forwards_batched": bat.prefill_forwards,
-        "wave_dedup_pages": bat.wave_dedup_pages + fus.wave_dedup_pages,
-        "token_agreement_fused": agree_fus,
-        "token_agreement_batched": agree_bat,
-        "wall_s_separate": t_seq, "wall_s_fused": t_fus,
-        # SLO fields computed from the fused run's lifecycle trace
-        "goodput": slo["goodput"],
-        "ttft_p50_s": slo["ttft_p50_s"], "ttft_p99_s": slo["ttft_p99_s"],
-        "tpot_p50_s": slo["tpot_p50_s"], "tpot_p99_s": slo["tpot_p99_s"],
-        "trace_path": trace_path, "trace_events": len(fus.tracer.events),
-    }
-    if verbose:
-        print(f"[ragged] {requests} queued shared-prefix requests "
-              f"(batch={batch}): {res['programs_separate']} -> "
-              f"{res['programs_fused']} programs "
-              f"({res['program_reduction']:.2f}x, one per cycle), "
-              f"prefill fwd {res['prefill_forwards_sequential']} -> "
-              f"{res['prefill_forwards_batched']} (wave dedupe), "
-              f"agreement fused {agree_fus:.1%} / batched {agree_bat:.1%}")
-        print(f"  slo (fused): goodput {res['goodput']:.2f}, ttft p50 "
-              f"{1e3 * (res['ttft_p50_s'] or 0):.1f} ms / p99 "
-              f"{1e3 * (res['ttft_p99_s'] or 0):.1f} ms, tpot p50 "
-              f"{1e3 * (res['tpot_p50_s'] or 0):.2f} ms; "
-              f"{res['trace_events']} trace events -> "
-              f"{os.path.basename(trace_path)}")
-    save_json("ragged_serve.json", res)
-    return res
-
-
 def _append_trajectory(point):
     """BENCH_serve.json accumulates one point per bench run, so the serving
     perf trend is visible across PRs (the driver diffs it)."""
@@ -900,9 +762,9 @@ def _append_trajectory(point):
 
 def run(*, arch="qwen2-72b", requests=10, batch=4, max_len=64, page_size=16,
         verbose=True, fast=False, workload="all"):
-    if workload in ("prefix", "overcommit", "adapt", "ragged"):
+    if workload in ("prefix", "overcommit", "adapt"):
         fn = {"prefix": run_prefix, "overcommit": run_overcommit,
-              "adapt": run_adapt, "ragged": run_ragged}[workload]
+              "adapt": run_adapt}[workload]
         res = fn(arch=arch, verbose=verbose, fast=fast)
         point = {"when": time.strftime("%Y-%m-%d %H:%M:%S"), "arch": arch,
                  "fast": fast, "summary": {workload: res}}
@@ -1009,7 +871,7 @@ def main(argv=None):
                     help="CI smoke: tiny trace, single iteration per config")
     ap.add_argument("--workload",
                     choices=["all", "mixed", "prefix", "overcommit",
-                             "adapt", "ragged"],
+                             "adapt"],
                     default="all",
                     help="mixed = the PR-2 mixed-length trace; prefix = the "
                          "shared-system-prompt trace (prefix cache on/off, "
@@ -1020,10 +882,7 @@ def main(argv=None):
                          "online-requantization trace (--kv-adapt on vs "
                          "off: requant-before-demote ordering, >=2x tokens "
                          "before the first host round trip, lm_precision "
-                         "accuracy gate); ragged = the saturated "
-                         "shared-prefix backlog (--fused on: fewer total "
-                         "programs at one launch/cycle + prefill-batch x "
-                         "prefix-cache wave dedupe, agreement gates)")
+                         "accuracy gate)")
     args = ap.parse_args(argv)
     run(arch=args.arch, requests=args.requests, batch=args.batch,
         max_len=args.max_len, page_size=args.page_size, fast=args.fast,

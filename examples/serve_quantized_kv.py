@@ -98,32 +98,12 @@ needs the paged pool + prefix cache):
   headroom exists). With ``--kv-adapt off`` all of the above is bitwise
   inert (asserted in tests/test_serve_fast.py).
 
-Since PR 7 steady-state serving can run **one program per scheduler
-cycle** — the fused ragged forward (``fused="on"`` / ``--fused on``; needs
-bucketed prefill):
-
-* every cycle launches ONE ``[rows, S]`` variable-length program
-  (``launch.steps.make_fused_step``): decode rows carry their single next
-  token (1 valid query), admission rows carry a prefill chunk padded to
-  the shared power-of-two bucket, each row with its own page table, start
-  position, and valid length. Decode no longer waits for prefill programs
-  — admission rounds ADVANCE the running slots (continuous batching with
-  zero prefill/decode program switches), and the LM head gathers only the
-  rows that emit a token this cycle, so prefill rows never pay vocab-width
-  compute. The only retrace axis is the S bucket: steady-state decode
-  (S=1) lowers to exactly the separate decode program, so fused output is
-  bitwise-identical to ``fused="off"`` at kv-bits {0, 8, 4} and mixed
-  profiles (asserted in tests/test_serve_fast.py); ``program_launches ==
-  cycles`` by construction, counted and printed by the server.
-* **prefix-aware wave dedupe** makes ``--prefill-batch`` compose with
-  ``--prefix-cache``: prompts admitted in the same wave that share a page-
-  aligned prefix elect a leader; followers wait, then alias the leader's
-  freshly written pages (refcounted, like a cache hit) and prefill only
-  their tail — fewer prefill forwards than sequential admission even when
-  the shared prefix was never cached before. On the saturated
-  shared-prefix backlog bench (``--workload ragged``) the composition cuts
-  prefill forwards 13 -> 9 and fused cuts total program launches 61 -> 52
-  at equal decode steps and 100% token agreement.
+**Prefix-aware wave dedupe** makes ``--prefill-batch`` compose with
+``--prefix-cache``: prompts admitted in the same wave that share a page-
+aligned prefix elect a leader; followers wait, then alias the leader's
+freshly written pages (refcounted, like a cache hit) and prefill only their
+tail — fewer prefill forwards than sequential admission even when the
+shared prefix was never cached before.
 
 Since PR 8 the whole serving path is **observable** (``--metrics on``;
 ``runtime.telemetry``):
@@ -145,9 +125,9 @@ Since PR 8 the whole serving path is **observable** (``--metrics on``;
   reduce to SLO metrics (``tracer.slo_summary()``): exact p50/p99 **TTFT**
   and **TPOT**, and **goodput** — the fraction of offered requests that
   finished by their ``deadline_step`` (printed after every ``--metrics
-  on`` run; the ragged/overcommit benches append them to BENCH_serve.json).
+  on`` run; the overcommit bench appends them to BENCH_serve.json).
 * ``--metrics-out metrics.jsonl`` streams a ``registry.snapshot()`` JSONL
-  line every ``--metrics-every`` scheduler cycles (counters + gauges +
+  line every ``--metrics-every`` decode steps (counters + gauges +
   histogram summaries) for dashboard scraping.
 * ``--metrics off`` (default) is the NullTracer path: telemetry lives
   entirely outside jitted code, so off is bitwise-identical to the
@@ -338,24 +318,6 @@ def main():
           f"copies); {srv_px.prefill_forwards_saved} prefill forwards saved")
     print(f"  release_prefix_cache() -> {srv_px.release_prefix_cache()} "
           f"leaked pages (0 = every refcount balanced)")
-
-    print("=== fused ragged forward: one program per scheduler cycle ===")
-    srv_sep = BatchedServer(cfg, params, batch_size=4, max_len=96,
-                            kv_bits=8, page_size=16, prefill_bucket=16,
-                            prefix_cache="on", prefill_batch=1)
-    reqs_sep = srv_sep.run(mk_shared(), verbose=True)
-    srv_fu = BatchedServer(cfg, params, batch_size=4, max_len=96, kv_bits=8,
-                           page_size=16, prefill_bucket=16,
-                           prefix_cache="on", fused="on")
-    reqs_fu = srv_fu.run(mk_shared(), verbose=True)
-    print(f"  programs: {srv_sep.program_launches} separate -> "
-          f"{srv_fu.program_launches} fused over {srv_fu.cycles} cycles "
-          f"(one per cycle: {srv_fu.program_launches == srv_fu.cycles}); "
-          f"wave dedupe aliased {srv_fu.wave_dedup_pages} page(s); "
-          f"token agreement {agreement(reqs_sep, reqs_fu):.1%} "
-          f"(bitwise-identical under single-threaded XLA)")
-    for s in (srv_sep, srv_fu):
-        assert s.release_prefix_cache() == 0
 
     print("=== tiered page store: host offload + SLO preemption + "
           "restart ===")

@@ -3,10 +3,8 @@
 quant_cast    — tiled fake-quant Q(I,F) (paper §2.1 conversion)
 pack/unpack   — k N-bit values <-> int32 lanes ("N-bit memory" on TPU HBM)
 quant_matmul  — int8-weight matmul, dequant-in-VMEM, per-channel scales
-kv_attention  — decode attention over a dense int8-quantized KV cache
-paged_kv_attention — decode attention over a paged int8/int4 KV pool
-                     (page-table gather via scalar prefetch; kv_attention
-                     is its identity-page-table special case)
+paged_kv_attention — decode and chunk attention over a paged int8/int4 KV
+                     pool (page-table gather via scalar prefetch)
 
 Use via ``repro.kernels.ops`` (jit'd, interpret-mode auto on CPU); oracles in
 ``repro.kernels.ref``.

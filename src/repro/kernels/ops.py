@@ -8,10 +8,8 @@ oracle in kernels.ref; tests/test_kernels.py sweeps shapes/dtypes
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
-from .kv_attention import kv_attention_decode
 from .paged_kv_attention import \
     paged_kv_attention_chunk as _paged_kv_attention_chunk
 from .paged_kv_attention import BLOCK_Q, paged_kv_attention_decode
@@ -57,14 +55,6 @@ def qmatmul(a, wq, scales, *, interpret=None):
     return quant_matmul(a, wq, scales, interpret=interpret)
 
 
-def kv_attention(q, k_q, v_q, kv_len, *, int_bits: int, frac_bits: int,
-                 interpret=None, block_t: int = 512):
-    interpret = _default_interpret() if interpret is None else interpret
-    return kv_attention_decode(q, k_q, v_q, kv_len, int_bits=int_bits,
-                               frac_bits=frac_bits, block_t=block_t,
-                               interpret=interpret)
-
-
 def paged_kv_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
                        kv_len, layer=0, *, bits: int = 8, interpret=None):
     """Decode attention over layer ``layer`` of stacked paged quantized KV
@@ -91,5 +81,5 @@ def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                                      interpret=interpret)
 
 
-__all__ = ["quant_cast", "pack", "unpack", "qmatmul", "kv_attention",
-           "paged_kv_attention", "paged_kv_attention_chunk", "ref"]
+__all__ = ["quant_cast", "pack", "unpack", "qmatmul", "paged_kv_attention",
+           "paged_kv_attention_chunk", "ref"]

@@ -1,14 +1,14 @@
 """Pallas kernel: GQA variable-length attention over a paged, quantized KV
 cache — ONE kernel for chunked prefill (S >= 1) and decode (S == 1).
 
-Generalizes ``kv_attention.py``'s dense int8 kernel to the paged pool of
-``repro.core.paged_kv``: KV history lives in fixed-size pages scattered
-through a shared pool, each page stored in its quantized container (int8
-grid, or a 4-bit grid lane-packed into int32 words along the head dim) with a
-per-page dequant scale. The dense kernel is a thin wrapper that builds an
-identity page table (see ``kv_attention.py``), and the historical decode
-entry point (:func:`paged_kv_attention_decode`) is now the single-query-row
-special case of the chunk kernel below.
+Reads the paged pool of ``repro.core.paged_kv``: KV history lives in
+fixed-size pages scattered through a shared pool, each page stored in its
+quantized container (int8 grid, or a 4-bit grid lane-packed into int32 words
+along the head dim) with a per-page dequant scale. The decode entry point
+(:func:`paged_kv_attention_decode`) is the single-query-row special case of
+the chunk kernel below. A dense (B, T, KV, hd) cache is the special case of a
+pool whose page table is the identity; tests/test_kernels.py checks that case
+against ``ref.kv_attention_ref``.
 
 Reachable via ``ops.paged_kv_attention`` / ``ops.paged_kv_attention_chunk``
 (oracle-verified in tests/test_kernels.py); the serving forward routes BOTH

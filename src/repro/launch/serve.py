@@ -38,16 +38,6 @@ The serving **hot path** is built around three ideas:
   disjoint pages, so batched == sequential bitwise (tests assert it).
   An ``OutOfPagesError`` mid-batch rolls back every partially admitted
   row before surfacing.
-* **One fused ragged forward per scheduler cycle** (--fused on): prefill
-  chunks and decode tokens ride ONE `[batch, bucket]` variable-length
-  program (``launch.steps.make_fused_step``) — each row carries its own
-  query length, start position, and page table (decode rows S=1 padded into
-  the shared bucket, prefill rows S=bucket), the LM head gathers only the
-  rows that emit a token this cycle, and steady-state serving runs exactly
-  one jitted program per cycle with zero prefill/decode program switches
-  (``cycles == program_launches``, asserted in tests). Fused output is
-  bitwise-identical to the separate-program reference at every kv-bits
-  setting (single-threaded XLA; see tests/test_serve_fast.py).
 * **Prefix-aware batched prefill** (wave dedupe): inside one admission
   wave, a prompt sharing page-aligned full chunks with an earlier same-wave
   prompt WAITS until that leader has written through the shared span, then
@@ -158,7 +148,7 @@ from ..runtime.telemetry import (MetricsRegistry, MetricsSnapshotter,
                                  SLOMonitor, make_tracer, metric_attr)
 from .mesh import make_serving_mesh
 from .scheduler import DeadlineMissPredictor, SchedPolicy, SLOScheduler
-from .steps import make_chunk_prefill_step, make_decode_step, make_fused_step
+from .steps import make_chunk_prefill_step, make_decode_step
 
 
 @dataclasses.dataclass
@@ -315,7 +305,6 @@ class BatchedServer:
     prefill_s = metric_attr("serve.prefill_s")
     decode_steps = metric_attr("serve.decode_steps")
     program_launches = metric_attr("serve.program_launches")
-    cycles = metric_attr("serve.cycles")
     wave_dedup_pages = metric_attr("serve.wave_dedup_pages")
     _gen_tokens = metric_attr("serve.gen_tokens")
     prefix_hit_tokens = metric_attr("serve.prefix_hit_tokens")
@@ -337,7 +326,7 @@ class BatchedServer:
                  sched: str = "fifo", admit_window: int = 4,
                  preempt: Optional[bool] = None,
                  kv_adapt: str = "off", adapt_pages: int = 0,
-                 adapt_floor_bits: int = 4, fused: str = "off",
+                 adapt_floor_bits: int = 4,
                  metrics: str = "off",
                  registry: Optional[MetricsRegistry] = None,
                  snapshot_out: Optional[str] = None,
@@ -512,24 +501,6 @@ class BatchedServer:
                                                  attn_impl=attn_impl),
                          mesh, 4)
             if self.prefill_mode == "bucketed" else None)
-        if fused not in ("on", "off"):
-            raise ValueError(f"fused must be 'on' or 'off', got {fused!r}")
-        if fused == "on" and self.prefill_mode != "bucketed":
-            raise ValueError("--fused on runs ONE ragged prefill+decode "
-                             "program per scheduler cycle; it needs the "
-                             "bucketed prefill path (paged cache, "
-                             "attention-only arch, exact MoE routing)")
-        self.fused = fused == "on"
-        self._fused = (_jit_on_mesh(make_fused_step(cfg, quant=self.quant,
-                                                    attn_impl=attn_impl),
-                                    mesh, 4)
-                       if self.fused else None)
-        if self.fused:
-            # steady-state span constants: every row decodes (valid_len 1)
-            # and every row emits — reused across steps so the only retrace
-            # axis anywhere in fused serving is the prefill bucket
-            self._ones_dev = jnp.ones((batch_size,), jnp.int32)
-            self._arange_dev = jnp.arange(batch_size, dtype=jnp.int32)
 
         paged_spec = None
         self.prefix_cache: Optional[PrefixCache] = None
@@ -621,8 +592,6 @@ class BatchedServer:
         self.prefill_s = 0.0
         self.decode_steps = 0
         self.program_launches = 0   # every jitted forward executed
-        self.cycles = 0             # scheduler cycles (fused rounds + span
-        #                             steps); fused == program_launches
         self.wave_dedup_pages = 0   # pages aliased off a same-wave leader
         self._gen_tokens = 0        # generated tokens (all run() calls)
         self.prefix_hit_tokens = 0        # prompt tokens served from cache
@@ -963,123 +932,6 @@ class BatchedServer:
         finally:
             self.prefill_s += time.perf_counter() - t0
 
-    # -- fused ragged cycles (--fused on) -----------------------------------
-    def _fused_round(self, pending: List[_PrefillJob]) -> List[_PrefillJob]:
-        """ONE ragged [B, bucket] program: every unfinished prefill job
-        contributes its next prompt chunk (padded to the round's shared
-        bucket, tail masked via valid_len) and every OTHER live slot
-        decodes one token in the same launch — prefill piggybacks on the
-        decode cycle instead of dispatching its own programs. Returns the
-        jobs still pending after the round."""
-        ready = [j for j in pending if j.wait_for is None]
-        bucket = max(_pow2_bucket(j.total - j.done, self.prefill_bucket)
-                     for j in ready)
-        prefilling = {j.slot for j in pending}
-        decode = [i for i in range(self.B) if self.slots[i] is not None
-                  and i not in prefilling]
-        tokens = np.zeros((self.B, bucket), np.int32)
-        starts = np.zeros((self.B,), np.int32)
-        valids = np.ones((self.B,), np.int32)
-        emit = np.zeros((self.B,), np.int32)   # fixed shape; host
-        #                                        discards padding entries
-        for j in ready:
-            off = j.start + j.done
-            toks = j.req.prompt[off:len(j.req.prompt) - 1]
-            valid = min(bucket, len(toks))
-            self._ensure_page(j.slot, off + valid - 1)
-            tokens[j.slot, :valid] = toks[:valid]
-            starts[j.slot] = off
-            valids[j.slot] = valid
-        for k, i in enumerate(decode):
-            self._ensure_page(i, int(self.pos[i]))
-            tokens[i, 0] = self.tokens[i]
-            starts[i] = self.pos[i]
-            emit[k] = i
-        pt = self._page_table_dev()
-        # private host copies nobody mutates later: plain asarray uploads
-        with self.tracer.span("fused_round",
-                              args={"bucket": bucket,
-                                    "prefill_rows": len(ready),
-                                    "decode_rows": len(decode),
-                                    "step": self._clock}):
-            tokens_dev, starts_dev, valids_dev, emit_dev = (
-                jnp.asarray(tokens), jnp.asarray(starts),
-                jnp.asarray(valids), jnp.asarray(emit))
-            with self.tracer.span("dispatch"):
-                nxt, _, self.caches = self._fused(
-                    self.params, tokens_dev, starts_dev, valids_dev,
-                    self.caches, pt, emit_dev)
-        self.program_launches += 1
-        self.cycles += 1
-        self.prefill_forwards += 1
-        for j in ready:
-            j.done += int(valids[j.slot])
-            self.pos[j.slot] = j.start + j.done
-        still = []
-        for j in pending:
-            if j.wait_for is None and j.done >= j.total:
-                self._finish_job(j)     # decode-eligible next round
-            else:
-                still.append(j)
-        # unblock followers BEFORE retirement can free a leader's pages
-        self._apply_wave_aliases(still)
-        still = [j for j in still if not j.finished]
-        if decode:
-            arr = np.asarray(nxt)
-            self.decode_steps += 1
-            self._gen_tokens += len(decode)
-            for k, i in enumerate(decode):
-                tok = int(arr[k])
-                req = self.slots[i]
-                if not req.out:
-                    self.tracer.req_first_token(req.rid)
-                    self.slo_monitor.note_first_token(req.rid)
-                req.out.append(tok)
-                self.tokens[i] = tok
-                self.pos[i] += 1
-                self.slot_gen[i] += 1
-                if (self.slot_gen[i] >= req.max_new
-                        or self.pos[i] >= self.max_len - 1):
-                    req.done = True
-                    self.slots[i] = None
-                    self._release_slot(i)
-                    # fused admission rounds decode without advancing the
-                    # run clock; the cycle's clock is the finish step
-                    self._note_finish(req, self._clock)
-        return still
-
-    def _run_fused_rounds(self, jobs: List[_PrefillJob]):
-        """Fused-mode admission: same accounting/rollback contract as
-        ``_run_prefills``, but every round is one ragged fused program that
-        also advances all non-prefilling decode slots — so admitting new
-        prompts costs ZERO extra program launches per cycle. Per-request
-        token streams are unchanged vs the separate-program path (each
-        row's math depends only on its own cache/position; the subprocess
-        identity test asserts bitwise equality)."""
-        t0 = time.perf_counter()
-        try:
-            pending = []
-            for job in jobs:
-                self.prefill_tokens += len(job.req.prompt)
-                self.prefill_forwards_saved += (
-                    self._n_chunks(len(job.req.prompt) - 1)
-                    - self._n_chunks(job.total))
-                if job.total == 0:
-                    self._finish_job(job)
-                else:
-                    pending.append(job)
-            if self.prefix_cache is not None:
-                self._plan_wave_dedupe(pending)
-            while pending:
-                pending = self._fused_round(pending)
-        except OutOfPagesError as err:
-            for job in jobs:
-                if not job.finished:
-                    self._rollback_admission(job, err)
-            raise
-        finally:
-            self.prefill_s += time.perf_counter() - t0
-
     # -- prefix sharing -----------------------------------------------------
     def _copy_pool_pages(self, src: int, dst: int):
         """Copy page ``src`` -> ``dst`` in EVERY attention layer's pool
@@ -1233,7 +1085,7 @@ class BatchedServer:
         self.slot_gen[i] = 0
         if self.prefill_mode == "bucketed":
             job = _PrefillJob(i, req, start)
-            if self.fused or self._prefill_group_cap() > 1:
+            if self._prefill_group_cap() > 1:
                 jobs.append(job)     # cycle runs these batched at the end
             else:
                 # sequential discipline (explicit --prefill-batch 1):
@@ -1417,10 +1269,7 @@ class BatchedServer:
             else:
                 self._admit_fifo(queue, jobs)
             if jobs:
-                if self.fused:
-                    self._run_fused_rounds(jobs)
-                else:
-                    self._run_prefills(jobs)
+                self._run_prefills(jobs)
 
     # -- preemption ---------------------------------------------------------
     def _preempt_gain(self, i: int) -> int:
@@ -1585,9 +1434,9 @@ class BatchedServer:
         return max(1, min(spans))
 
     def _note_finish(self, req: Request, step: int) -> None:
-        """Retirement bookkeeping shared by the span-boundary and fused
-        paths: the deadline-miss counter is measured on the decode-step
-        clock (deterministic), the tracer closes the request's record,
+        """Retirement bookkeeping at a span boundary: the deadline-miss
+        counter is measured on the decode-step clock (deterministic), the
+        tracer closes the request's record,
         the rolling SLO window absorbs the outcome, and (predictor on)
         the retired request's admission-time features become one SGD
         example with the miss as its label."""
@@ -1660,15 +1509,13 @@ class BatchedServer:
                       f"free={self.allocator.num_free}"
                       if self.paged else "dense")
             steps = self.decode_steps - steps0
-            mode = "fused" if self.fused else self.prefill_mode
             print(f"[serve] {steps} decode steps, "
                   f"{self.prefill_forwards - pf0} prefill forwards "
-                  f"({mode}), {len(requests)} requests, "
+                  f"({self.prefill_mode}), {len(requests)} requests, "
                   f"{gen_tokens / max(dt, 1e-9):,.1f} tok/s "
                   f"({steps * self.B / max(dt, 1e-9):,.1f} "
                   f"tok-slots/s, {layout}, attn={self.attn_impl}, "
-                  f"{self.program_launches} programs / "
-                  f"{self.cycles} cycles)")
+                  f"{self.program_launches} programs)")
             if self.prefix_cache is not None:
                 s = self.prefix_cache.stats()
                 print(f"[serve] prefix cache: {s['hits']}/{s['lookups']} "
@@ -1912,22 +1759,9 @@ class ServeLoop:
                             srv._ensure_page(i, int(srv.pos[i]))
                     pt = srv._page_table_dev() if srv.paged else None
                     with srv.tracer.span("dispatch"):
-                        if srv.fused:
-                            # steady state: the SAME fused program as
-                            # admission rounds at S=1 — every row decodes,
-                            # every row emits. Bitwise-identical to
-                            # srv.decode (the gathers are identity copies;
-                            # see make_fused_step).
-                            nxt, _, srv.caches = srv._fused(
-                                srv.params, tokens_dev[:, None], pos_dev,
-                                srv._ones_dev, srv.caches, pt,
-                                srv._arange_dev)
-                        else:
-                            nxt, _, srv.caches = srv.decode(
-                                srv.params, tokens_dev, pos_dev,
-                                srv.caches, pt)
+                        nxt, _, srv.caches = srv.decode(
+                            srv.params, tokens_dev, pos_dev, srv.caches, pt)
                     srv.program_launches += 1
-                    srv.cycles += 1
                     srv._count_attn_pages(srv.pos, srv.pos + 1, 1)
                     nxt.copy_to_host_async()
                     fetches.append((nxt, tuple(srv.slots)))
@@ -1976,7 +1810,7 @@ class ServeLoop:
         self.clock = clock + span
         srv.slo_monitor.advance(span)
         if srv._snapshotter is not None:
-            srv._snapshotter.maybe_emit(srv.cycles)
+            srv._snapshotter.maybe_emit(srv.decode_steps)
         return True
 
     def close(self) -> None:
@@ -2018,15 +1852,6 @@ def main(argv=None):
                          "the batch size — intra-wave prefix sharing is "
                          "handled by wave dedupe, so this composes with "
                          "--prefix-cache; 1 = sequential reference)")
-    ap.add_argument("--fused", choices=["on", "off"], default="off",
-                    help="on = ONE ragged [batch, bucket] program per "
-                         "scheduler cycle: prefill chunks and decode "
-                         "tokens share a single variable-length forward "
-                         "(per-row start/length/page-table, LM head only "
-                         "on emitting rows), bitwise-identical to the "
-                         "separate-program path; needs bucketed prefill. "
-                         "--prefill-batch is ignored in fused mode (every "
-                         "cycle is already one program)")
     ap.add_argument("--kv-profile", default="",
                     help="path to a core.policy.PrecisionPolicy JSON (e.g. "
                          "core.search output): per-layer KV containers — "
@@ -2121,10 +1946,10 @@ def main(argv=None):
                          "--metrics on")
     ap.add_argument("--metrics-out", default="",
                     help="path: append a JSONL registry snapshot every "
-                         "--metrics-every scheduler cycles. Implies "
+                         "--metrics-every decode steps. Implies "
                          "--metrics on")
     ap.add_argument("--metrics-every", type=int, default=50,
-                    help="scheduler cycles between JSONL snapshots "
+                    help="decode steps between JSONL snapshots "
                          "(with --metrics-out)")
     args = ap.parse_args(argv)
     enable_compile_cache()
@@ -2161,7 +1986,7 @@ def main(argv=None):
                         kv_adapt=args.kv_adapt,
                         adapt_pages=args.kv_adapt_pages,
                         adapt_floor_bits=args.kv_adapt_floor,
-                        fused=args.fused, metrics=args.metrics,
+                        metrics=args.metrics,
                         snapshot_out=args.metrics_out or None,
                         snapshot_every=args.metrics_every,
                         predictor=args.predictor,

@@ -11,7 +11,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..models.common import lm_head
 from ..models.transformer import (forward, forward_hidden, init_cache,
                                   init_model, train_loss)
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
@@ -101,7 +100,7 @@ def make_chunk_prefill_step(cfg, *, quant=None, attn_impl: str = "gather"):
     ``forward_hidden`` and not ``forward``.
 
     The returned function is named ``chunk_prefill_step`` (likewise
-    ``decode_step`` and ``fused_step`` below), so its XLA module is
+    ``decode_step`` below), so its XLA module is
     ``jit_chunk_prefill_step`` in compiles and profiler traces.
 
     ``attn_impl`` routes the chunk's attention reads exactly like decode
@@ -125,55 +124,6 @@ def make_chunk_prefill_step(cfg, *, quant=None, attn_impl: str = "gather"):
         return aux["caches"]
 
     return chunk_prefill_step
-
-
-def make_fused_step(cfg, *, quant=None, attn_impl: str = "gather"):
-    """fn(params, tokens (R, S), start_pos (R,), valid_len (R,), caches,
-    page_table (R, NP), emit_idx (R,)) -> (next_tokens (R,), logits (R, V),
-    caches).
-
-    ONE ragged variable-length program per scheduler cycle: every row is
-    either a decode row (its single next token, ``valid_len == 1``) or a
-    prefill chunk row (``valid_len`` real prompt tokens padded up to the
-    shared bucket ``S``), each carrying its own page table and start
-    position. Padded tails are masked out of the pool write through the
-    ``valid_len`` scratch-page redirect, and their attention outputs are
-    garbage nobody reads — the causal bound of every REAL query position is
-    tighter than the padded KV extent, so garbage keys never leak into real
-    rows (see ``route_paged_attention``).
-
-    The LM head runs only on ``emit_idx`` rows (the rows that actually
-    sample a token this cycle — decode rows, plus prefill rows finishing
-    their prompt): hidden states are gathered per row at the row's LAST
-    valid position before the (len(emit_idx), 1, V) head GEMM, so prefill
-    rows riding along never pay vocab-width compute. Callers keep
-    ``emit_idx`` a fixed (R,) shape (padded with row 0 and discarded on the
-    host) so the only retrace axis is the S bucket.
-
-    Steady state (all rows decoding: S == 1, ``emit_idx == arange(R)``,
-    ``valid_len == 1``) lowers to exactly the ``make_decode_step`` program —
-    the gathers are identity copies and the head GEMM has the same shape and
-    operands — so fused decode is bitwise-identical to the separate-program
-    path, which the subprocess identity test in tests/test_serve_fast.py
-    asserts at kv-bits {0, 8, 4}."""
-
-    def fused_step(params, tokens, start_pos, valid_len, caches, page_table,
-                   emit_idx):
-        batch = {"tokens": tokens}
-        x, aux = forward_hidden(params, batch, cfg, quant=quant,
-                                caches=caches, cache_pos=start_pos,
-                                page_table=page_table, attn_impl=attn_impl,
-                                kv_valid_len=valid_len)
-        # hidden of each emitting row at its last REAL position
-        h = jnp.take(x, emit_idx, axis=0)                       # (E, S, D)
-        last = jnp.take(valid_len, emit_idx) - 1                # (E,)
-        h = jnp.take_along_axis(h, last[:, None, None], axis=1)  # (E, 1, D)
-        tied = params["embed"]["table"] if cfg.tie_embeddings else None
-        logits = lm_head(params.get("head"), h, tied_table=tied)[:, 0]
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, logits, aux["caches"]
-
-    return fused_step
 
 
 def make_decode_step(cfg, *, quant=None, greedy: bool = True,

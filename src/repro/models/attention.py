@@ -4,8 +4,8 @@ Three memory-relevant design points, all tied to the paper:
 
 * **Chunked (online-softmax) attention** — scores never materialize beyond a
   (..., S, chunk) tile, so 32k prefill and 500k decode stay within HBM. This
-  is the pure-JAX analogue of the Pallas ``kv_attention`` kernel and serves
-  as its oracle at integration level.
+  is the pure-JAX analogue of the Pallas ``paged_kv_attention`` kernel and
+  serves as its oracle at integration level.
 * **Quantized KV cache** — the paper's per-layer "data" quantization applied
   to the tensor that dominates decode traffic. The cache stores an int8/int16
   integer grid; (scale, qmin, qmax) ride through ``lax.scan`` as per-layer

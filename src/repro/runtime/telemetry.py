@@ -1,7 +1,7 @@
 """Serving telemetry substrate: a unified metrics registry + a span tracer.
 
 The serving stack (paged pool, prefix cache, quant tier, host tier, SLO
-scheduler, fused step) grew one ad-hoc instance counter per feature and a
+scheduler) grew one ad-hoc instance counter per feature and a
 ``verbose=True`` print block. This module replaces that with two small
 primitives every layer shares:
 
@@ -37,7 +37,7 @@ primitives every layer shares:
   construction (the same contract ``--kv-adapt off`` keeps).
 
 * :class:`MetricsSnapshotter` — a periodic JSONL metrics stream: one
-  ``registry.snapshot()`` line every N scheduler cycles.
+  ``registry.snapshot()`` line every N decode steps.
 
 Both tracers' spans enter ``jax.profiler.TraceAnnotation("serve.<name>")``
 (:func:`annotation`), so whenever a profiler session runs the serving
@@ -461,7 +461,7 @@ class Tracer:
     * ``M`` metadata (process/track names, emitted lazily per track).
 
     Track layout: pid 0, tid 0 = the serving engine (admission waves,
-    prefill chunks, fused rounds, decode spans); tid ``1 + rid`` = one
+    prefill chunks, decode spans); tid ``1 + rid`` = one
     track per request. ``args.step`` carries the decode-step clock where
     known, so goodput is computable from the trace alone.
     """
@@ -741,9 +741,9 @@ def make_tracer(mode: str, name: str = "serve"):
 
 class MetricsSnapshotter:
     """Periodic JSONL metrics stream: one ``registry.snapshot()`` line per
-    ``every`` scheduler cycles (plus whatever ``emit`` is called with).
-    Lines carry the cycle count and a wall timestamp; the file is append-
-    mode so restarts extend the stream."""
+    ``every`` decode steps (plus whatever ``emit`` is called with).
+    Lines carry the decode-step count (key ``cycle``) and a wall
+    timestamp; the file is append-mode so restarts extend the stream."""
 
     def __init__(self, registry: MetricsRegistry, path: str,
                  every: int = 50):

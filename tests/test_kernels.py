@@ -1,6 +1,8 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles (interpret
 mode on CPU). Hypothesis drives the shape space; tolerances are exact for
 grid ops (quantization is deterministic) and ~1e-4 for float accumulation."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -88,8 +90,26 @@ def test_quant_matmul_matches_ref(m, k, n, adt):
 
 
 # ---------------------------------------------------------------------------
-# kv_attention
+# dense int8 cache through the paged kernel
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("frac_bits", "page_size"))
+def _dense_paged_attention(q, k_q, v_q, kv_len, frac_bits, page_size):
+    """Decode attention over a dense (B, T, KV, hd) int8 Q(I,F) cache, read
+    as a one-layer paged pool whose page table is the identity (row b's
+    page p is pool page b * NP + p) and whose page scales are all 2^-F."""
+    B, T, KV, hd = k_q.shape
+    pad = ((0, 0), (0, (-T) % page_size), (0, 0), (0, 0))
+    k_q, v_q = jnp.pad(k_q, pad), jnp.pad(v_q, pad)
+    NP = k_q.shape[1] // page_size
+    k_pages = k_q.reshape(1, B * NP, page_size, KV, hd)
+    v_pages = v_q.reshape(1, B * NP, page_size, KV, hd)
+    pt = jnp.arange(B * NP, dtype=jnp.int32).reshape(B, NP)
+    scale = jnp.full((1, B * NP), 2.0 ** -frac_bits, jnp.float32)
+    lens = jnp.full((B,), kv_len, jnp.int32)
+    return ops.paged_kv_attention(q, k_pages, v_pages, scale, scale, pt,
+                                  lens, bits=8)
+
+
 @settings(**_SETTINGS)
 @given(b=st.integers(1, 3), kv=st.sampled_from([1, 2, 4]),
        g=st.sampled_from([1, 2, 4]), hd=st.sampled_from([16, 32, 64]),
@@ -103,8 +123,7 @@ def test_kv_attention_matches_ref(b, kv, g, hd, t, frac):
     v_q = jax.random.randint(jax.random.fold_in(key, 2), (b, t, kv, hd),
                              -128, 128, jnp.int32).astype(jnp.int8)
     kv_len = max(1, t - 3)
-    out = ops.kv_attention(q, k_q, v_q, kv_len, int_bits=2, frac_bits=frac,
-                           block_t=64)
+    out = _dense_paged_attention(q, k_q, v_q, kv_len, frac, min(64, t))
     expect = ref.kv_attention_ref(q, k_q, v_q, 2, frac, kv_len)
     np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
 
@@ -402,10 +421,11 @@ def test_kv_attention_masks_tail():
                              jnp.int32).astype(jnp.int8)
     v_q = jax.random.randint(jax.random.fold_in(key, 1), (1, 64, 2, 32),
                              -128, 128, jnp.int32).astype(jnp.int8)
-    out1 = ops.kv_attention(q, k_q, v_q, 10, int_bits=2, frac_bits=5,
-                            block_t=16)
+    out1 = _dense_paged_attention(q, k_q, v_q, 10, 5, 16)
+    np.testing.assert_allclose(out1, ref.kv_attention_ref(q, k_q, v_q, 2, 5,
+                                                          10),
+                               rtol=1e-4, atol=1e-4)
     k_q2 = k_q.at[:, 10:].set(127)
     v_q2 = v_q.at[:, 10:].set(-128)
-    out2 = ops.kv_attention(q, k_q2, v_q2, 10, int_bits=2, frac_bits=5,
-                            block_t=16)
+    out2 = _dense_paged_attention(q, k_q2, v_q2, 10, 5, 16)
     np.testing.assert_allclose(out1, out2, rtol=1e-6)

@@ -221,110 +221,40 @@ def test_batched_admission_rolls_back_on_out_of_pages(smoke_model):
 
 
 # ---------------------------------------------------------------------------
-# Fused ragged step == separate prefill/decode programs, token for token
+# Compile-count discipline of the two serving programs
 # ---------------------------------------------------------------------------
-# The trace mixes same-bucket admission waves, a multi-chunk prompt (21),
-# and staggered retirements, so fused cycles cover every ragged shape:
-# pure-decode (S=1), mixed prefill+decode rows, and prefill-only rounds.
-# With prefix on, every prompt shares an 11-token system prefix, so fused
-# admission ALSO exercises the prefix-aware wave dedupe.
-_FUSED_IDENTITY_SCRIPT = r"""
-import jax, numpy as np
-jax.config.update("jax_platform_name", "cpu")
-from repro.configs.registry import get_smoke_config
-from repro.launch.serve import BatchedServer, Request
-from repro.models.transformer import init_model
-
-cfg = get_smoke_config("qwen2-72b")
-params = init_model(jax.random.PRNGKey(0), cfg)
-sys_prompt = np.random.default_rng(11).integers(
-    0, cfg.vocab_size, 11).astype(np.int32)
-
-def mk(shared):
-    r = np.random.default_rng(13)
-    reqs = []
-    for i, L in enumerate([9, 9, 5, 21, 9]):
-        p = r.integers(0, cfg.vocab_size, L).astype(np.int32)
-        if shared:
-            p = np.concatenate([sys_prompt, p])
-        reqs.append(Request(i, p, 5 + (i % 3)))
-    return reqs
-
-for kv_bits in (0, 8, 4):
-    for prefix in ("off", "on"):
-        kw = dict(batch_size=4, max_len=48, kv_bits=kv_bits, page_size=8,
-                  prefill="bucketed", prefill_bucket=8, prefix_cache=prefix)
-        sep = BatchedServer(cfg, params, fused="off", **kw)
-        out_sep = sep.run(mk(prefix == "on"))
-        fus = BatchedServer(cfg, params, fused="on", **kw)
-        out_fus = fus.run(mk(prefix == "on"))
-        for a, b in zip(out_sep, out_fus):
-            assert a.out == b.out, (kv_bits, prefix, a.rid, a.out, b.out)
-        assert all(r.done for r in out_fus)
-        # the fused contract: ONE jitted program per scheduler cycle
-        assert fus.program_launches == fus.cycles, (
-            fus.program_launches, fus.cycles)
-        if fus.prefix_cache is not None:     # cached pages are retained...
-            assert fus.prefix_cache.clear() == 0   # ...but not leaked
-        assert fus.allocator.num_free == fus.allocator.num_usable
-        print(f"kv_bits={kv_bits} prefix={prefix} identical "
-              f"({fus.program_launches} programs / {fus.cycles} cycles)")
-print("FUSED_IDENTITY_OK")
-"""
-
-
-def test_fused_matches_separate_programs():
-    """The fused ragged step (one [rows, S] variable-length forward per
-    scheduler cycle) == the separate prefill-chunk + decode-span program
-    path, token for token, at kv-bits {0, 8, 4} x prefix-cache {off, on} —
-    with exactly one program launch per cycle.
-
-    Runs in a subprocess with single-threaded XLA: multi-threaded XLA:CPU
-    GEMMs are not bitwise deterministic under thread contention, and exact
-    argmax token identity needs bitwise-equal logits."""
-    import os
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_cpu_multi_thread_eigen=false "
-                        "intra_op_parallelism_threads=1 "
-                        + env.get("XLA_FLAGS", ""))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
-        + [os.path.join(os.path.dirname(__file__), "..", "src")])
-    res = subprocess.run([sys.executable, "-c", _FUSED_IDENTITY_SCRIPT],
-                         env=env, capture_output=True, text=True,
-                         timeout=1800)
-    assert res.returncode == 0, res.stdout + res.stderr
-    assert "FUSED_IDENTITY_OK" in res.stdout
-
-
-def test_fused_steady_state_one_program_per_cycle(smoke_model):
-    """Compile-count discipline: a fused trace launches exactly one program
-    per scheduler cycle, and the fused step retraces only per S bucket —
-    one steady-state decode shape (S=1) plus one per prefill bucket —
-    never per cycle."""
+def test_separate_programs_compile_once_per_shape(smoke_model):
+    """The decode step traces once, and the chunk-prefill step once per
+    prefill bucket it runs, however the trace interleaves admissions and
+    retirements: a benchmark that warms those shapes compiles nothing in
+    its measured window. Every launch is a decode step or a prefill
+    forward. Prompts of 5, 9 and 13 tokens prefill in buckets 4 and 8
+    (13 -> 8 + 4) at batch 2, and staggered answer lengths refill slots
+    mid-run."""
     cfg, params = smoke_model
-    srv = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
+    srv = BatchedServer(cfg, params, batch_size=2, max_len=48, kv_bits=8,
                         page_size=8, prefill="bucketed", prefill_bucket=8,
-                        fused="on")
+                        prefill_batch=1)
+    shapes = set()
+    group = srv._prefill_group
+
+    def group_rec(rows, bucket):
+        shapes.add((len(rows), bucket))
+        return group(rows, bucket)
+
+    srv._prefill_group = group_rec
     rng = np.random.default_rng(3)
-    reqs = [Request(i, rng.integers(0, cfg.vocab_size, 9).astype(np.int32),
-                    6) for i in range(3)]
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, L).astype(np.int32),
+                    3 + 2 * i) for i, L in enumerate([9, 5, 13, 9, 5])]
     out = srv.run(reqs)
     assert all(r.done for r in out)
-    assert srv.decode_steps > 0
-    assert srv.program_launches == srv.cycles
-    # two traced shapes total: the bucket-8 admission rounds and S=1 decode
-    assert srv._fused._cache_size() <= 2, srv._fused._cache_size()
+    assert shapes == {(1, 4), (1, 8)}, shapes
+    assert srv.decode._cache_size() == 1, srv.decode._cache_size()
+    assert srv._chunk_prefill._cache_size() == len(shapes), (
+        srv._chunk_prefill._cache_size(), shapes)
+    assert srv.program_launches == srv.decode_steps + srv.prefill_forwards, (
+        srv.program_launches, srv.decode_steps, srv.prefill_forwards)
     assert srv.allocator.num_free == srv.allocator.num_usable
-
-
-def test_fused_requires_bucketed_prefill(smoke_model):
-    cfg, params = smoke_model
-    with pytest.raises(ValueError, match="fused"):
-        BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
-                      page_size=8, prefill="stepwise", fused="on")
 
 
 # ---------------------------------------------------------------------------

@@ -17,13 +17,12 @@ run, and the metrics on/off bitwise-identity contract.
 * ``--metrics off`` must be token-identical to a server built without the
   flag, and ``--metrics on`` must change tokens nowhere, at kv-bits
   {0, 8, 4} (subprocess, single-threaded XLA — same pattern as the other
-  bitwise-identity suites); fused mode must keep program_launches ==
-  cycles as read through the registry.
+  bitwise-identity suites).
 * Under a profiler session the serving loop's spans appear as
   ``serve.*`` host events, nested tick > admission > admit and
   tick > decode_span > decode_step > dispatch, whatever the metrics mode;
-  the device programs lower as ``jit_decode_step``,
-  ``jit_chunk_prefill_step`` and ``jit_fused_step``, on a mesh too.
+  the device programs lower as ``jit_decode_step`` and
+  ``jit_chunk_prefill_step``, on a mesh too.
 """
 import glob
 import json
@@ -383,30 +382,13 @@ for kv_bits in (0, 8, 4):
     assert len(on.tracer.events) > 0 and len(off.tracer.events) == 0
     assert on.tracer.slo_summary()["finished"] == len(out_on)
     print(f"kv_bits={kv_bits} tokens identical across seed/off/on")
-
-# fused mode with metrics on: the one-launch-per-cycle contract holds as
-# read THROUGH the registry (the gate the ragged bench re-asserts)
-fus = BatchedServer(cfg, params, batch_size=3, max_len=32, kv_bits=8,
-                    page_size=8, prefill="bucketed", prefill_bucket=8,
-                    fused="on", metrics="on")
-out_fus = fus.run(mk())
-assert all(r.done for r in out_fus)
-assert (fus.metrics.counter("serve.program_launches").value
-        == fus.metrics.counter("serve.cycles").value > 0)
-sep = BatchedServer(cfg, params, batch_size=3, max_len=32, kv_bits=8,
-                    page_size=8, prefill="bucketed", prefill_bucket=8,
-                    fused="off")
-out_sep = sep.run(mk())
-for a, b in zip(out_sep, out_fus):
-    assert a.out == b.out, ("fused", a.rid, a.out, b.out)
 print("METRICS_IDENTITY_OK")
 """
 
 
 def test_metrics_modes_are_token_neutral():
     """--metrics off is token-identical to a server built without the flag,
-    and --metrics on changes tokens nowhere, at kv-bits {0, 8, 4}; fused
-    mode keeps program_launches == cycles as read through the registry.
+    and --metrics on changes tokens nowhere, at kv-bits {0, 8, 4}.
 
     Runs in a subprocess with single-threaded XLA: multi-threaded XLA:CPU
     GEMMs are not bitwise deterministic under thread contention, and exact
@@ -629,24 +611,22 @@ _SPAN_PARENTS = {
     "admission": {"tick"},
     "admit": {"admission"},
     "prefill_chunk": {"admission"},
-    "fused_round": {"admission"},
     "decode_span": {"tick"},
     "decode_step": {"decode_span"},
     "span_fetch": {"decode_span"},
-    "dispatch": {"decode_step", "prefill_chunk", "fused_round"},
+    "dispatch": {"decode_step", "prefill_chunk"},
     "retire": {"tick"},
 }
 
 
-@pytest.mark.parametrize("fused", ["off", "on"])
-def test_profiler_trace_holds_serving_spans(smoke_model, tmp_path, fused):
+def test_profiler_trace_holds_serving_spans(smoke_model, tmp_path):
     """``metrics="off"``: a profiler session over a ServeLoop run still
     holds every serving span, nested as the loop runs them, with the
     host values the loop had at hand as args."""
     cfg, params = smoke_model
     srv = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
                         page_size=8, prefill="bucketed", prefill_bucket=8,
-                        fused=fused, metrics="off")
+                        metrics="off")
     rng = np.random.default_rng(5)
     reqs = [Request(i, rng.integers(0, cfg.vocab_size, 6 + 3 * i)
                     .astype(np.int32), 3 + i) for i in range(3)]
@@ -663,9 +643,7 @@ def test_profiler_trace_holds_serving_spans(smoke_model, tmp_path, fused):
     assert all(r.done for r in reqs)
     assert srv.tracer.events == []                 # off records nothing
     names = {e["name"] for e in events}
-    expect = set(_SPAN_PARENTS) - {"fused_round" if fused == "off"
-                                   else "prefill_chunk"}
-    assert names == expect, names
+    assert names == set(_SPAN_PARENTS), names
     for ev in events:
         assert _parent(ev, events) in _SPAN_PARENTS[ev["name"]], ev
     count = lambda n: sum(1 for e in events if e["name"] == n)
@@ -676,13 +654,10 @@ def test_profiler_trace_holds_serving_spans(smoke_model, tmp_path, fused):
     assert sum(e["stats"]["steps"] for e in spans) == count("decode_step")
     assert all(e["stats"]["rows"] >= 1 for e in spans)
     assert count("span_fetch") == len(spans)
-    launches = count("decode_step") + count("prefill_chunk") \
-        + count("fused_round")
-    assert count("dispatch") == launches
-    if fused == "off":
-        assert count("decode_step") == srv.decode_steps - steps0
-        assert all(e["stats"]["bucket"] <= 8 for e in events
-                   if e["name"] == "prefill_chunk")
+    assert count("dispatch") == count("decode_step") + count("prefill_chunk")
+    assert count("decode_step") == srv.decode_steps - steps0
+    assert all(e["stats"]["bucket"] <= 8 for e in events
+               if e["name"] == "prefill_chunk")
 
 
 def test_chrome_export_keeps_span_names(smoke_model):
@@ -706,17 +681,15 @@ def test_chrome_export_keeps_span_names(smoke_model):
 
 
 def test_device_programs_have_stable_names(smoke_model):
-    """The serving programs lower as ``jit_decode_step``,
-    ``jit_chunk_prefill_step`` and ``jit_fused_step`` — jitted directly
-    and through ``_jit_on_mesh`` on a 1-device mesh — never ``jit_step``."""
+    """The serving programs lower as ``jit_decode_step`` and
+    ``jit_chunk_prefill_step`` — jitted directly and through
+    ``_jit_on_mesh`` on a 1-device mesh — never ``jit_step``."""
     from repro.launch.mesh import make_serving_mesh
     from repro.launch.serve import _jit_on_mesh
-    from repro.launch.steps import (make_chunk_prefill_step,
-                                    make_decode_step, make_fused_step)
+    from repro.launch.steps import make_chunk_prefill_step, make_decode_step
     cfg, params = smoke_model
     srv = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
-                        page_size=8, prefill="bucketed", prefill_bucket=8,
-                        fused="on")
+                        page_size=8, prefill="bucketed", prefill_bucket=8)
     B, NP = srv.B, srv.np_max
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
     pt = i32(B, NP)
@@ -726,9 +699,6 @@ def test_device_programs_have_stable_names(smoke_model):
         "chunk_prefill_step": (make_chunk_prefill_step,
                                (i32(B, 8), i32(B), i32(B), srv.caches,
                                 pt), 4),
-        "fused_step": (make_fused_step,
-                       (i32(B, 8), i32(B), i32(B), srv.caches, pt,
-                        i32(B)), 4),
     }
     for mesh in (None, make_serving_mesh(1)):
         for name, (make, args, caches_arg) in programs.items():
