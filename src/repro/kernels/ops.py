@@ -67,17 +67,18 @@ def paged_kv_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
 
 
 def paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
-                             page_table, q_start, kv_len, layer=0, *,
-                             bits: int = 8, block_q: int = BLOCK_Q,
+                             page_table, q_start, kv_len, layer=0, work=None,
+                             *, bits: int = 8, block_q: int = BLOCK_Q,
                              interpret=None):
     """Variable-length (S >= 1) chunk attention over a paged quantized KV
     pool — the prefill-chunk generalization of ``paged_kv_attention`` (see
     kernels.paged_kv_attention for shapes). q: (B, S, H, hd); ``q_start``
-    (B,) is the absolute position of each row's first chunk query."""
+    (B,) is the absolute position of each row's first chunk query;
+    ``work``: the call's work list, built by the kernel when None."""
     interpret = _default_interpret() if interpret is None else interpret
     return _paged_kv_attention_chunk(q, k_pages, v_pages, k_scale, v_scale,
                                      page_table, q_start, kv_len, layer,
-                                     bits=bits, block_q=block_q,
+                                     work, bits=bits, block_q=block_q,
                                      interpret=interpret)
 
 

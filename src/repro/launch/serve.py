@@ -834,15 +834,32 @@ class BatchedServer:
         """One program call's paged-kernel walk on the registry (each of
         its layers walks the same table): ``serve.attn_pages_live``, the
         table entries the kernel fetches, and ``serve.attn_pages_table``,
-        those a walk of every entry would visit."""
+        those a walk of every entry would visit; ``serve.attn_grid_steps``,
+        the grid steps the kernel runs (its work list's items), and
+        ``serve.attn_grid_box``, those of a grid over every row, query
+        block and page block."""
         if self.attn_impl != "pallas":
             return
         from ..kernels.paged_kv_attention import page_walk
-        walked, table = page_walk(q_start, kv_len, seq_len=seq_len,
-                                  num_table_pages=self.np_max,
-                                  page_size=self.page_size)
-        self.metrics.counter("serve.attn_pages_live").inc(walked)
-        self.metrics.counter("serve.attn_pages_table").inc(table)
+        counts = page_walk(q_start, kv_len, seq_len=seq_len,
+                           num_table_pages=self.np_max,
+                           page_size=self.page_size, ppb=self._attn_ppb)
+        for name, n in zip(("attn_pages_live", "attn_pages_table",
+                            "attn_grid_steps", "attn_grid_box"), counts):
+            self.metrics.counter(f"serve.{name}").inc(n)
+
+    @functools.cached_property
+    def _attn_ppb(self) -> int:
+        """Table entries a grid step of the paged kernel walks, as the
+        kernel derives them from the first paged layer's pool (a shard of
+        it on the server's mesh)."""
+        from ..kernels.paged_kv_attention import pages_per_block
+        from ..models.attention import paged_kernel_pages
+        pool = next(c for c in jax.tree_util.tree_leaves(
+            self.caches, is_leaf=lambda c: isinstance(c, dict)
+            and "k_pages" in c) if isinstance(c, dict))
+        return pages_per_block(num_table_pages=self.np_max,
+                               **paged_kernel_pages(pool, self.mesh))
 
     def _finish_job(self, job: _PrefillJob):
         """Seal a prefilled slot: clock at the last prompt token (which the

@@ -153,7 +153,7 @@ def paged_cache_view(cache, page_table, *, head_dim, dtype, layer=None):
 
 def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
                           cfg, attn_impl: str = "gather",
-                          operand_dtype=jnp.float32, layer=None):
+                          operand_dtype=jnp.float32, layer=None, work=None):
     """Unified variable-length paged attention entry point.
 
     ONE routing layer for every paged attention read — chunked prefill
@@ -178,7 +178,9 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
     Padded chunk tails need no special masking here: the causal bound of
     every REAL query is tighter than the padding positions, and padded
     queries' outputs are garbage nobody reads (their pool writes were
-    scratch-redirected). Returns (B, S, H, hd) in q.dtype.
+    scratch-redirected). ``work``: the kernel's work list for the step
+    (``paged_kernel_work``), or None for the kernel to build it. Returns
+    (B, S, H, hd) in q.dtype.
     """
     B, S, H, hd = q.shape
     base = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1),
@@ -187,7 +189,8 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
         if not cfg.causal:
             raise ValueError("the paged attention kernel masks causally; "
                              "a non-causal config needs attn_impl='gather'")
-        return _paged_kernel_attention(q, cache, page_table, base, layer)
+        return _paged_kernel_attention(q, cache, page_table, base, layer,
+                                       work)
     kd, vd = paged_cache_view(cache, page_table, head_dim=hd,
                               dtype=operand_dtype, layer=layer)
     return attend_chunked(q, kd, vd, positions, 0, causal=cfg.causal,
@@ -195,36 +198,72 @@ def route_paged_attention(q, cache, page_table, positions, cache_pos, *,
                           operand_dtype=operand_dtype)
 
 
-def _paged_kernel_attention(q, cache, page_table, base, layer):
+def _kernel_tp(cache, mesh=None) -> int:
+    """Shards the paged kernel runs on: the "model" axis of ``mesh`` (the
+    abstract mesh of the trace when None) where it divides the pool's KV
+    heads (tensor-parallel serving), else 1."""
+    mesh = jax.sharding.get_abstract_mesh() if mesh is None else mesh
+    tp = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
+    return tp if tp > 1 and cache["k_pages"].shape[-2] % tp == 0 else 1
+
+
+def paged_kernel_pages(cache, mesh=None) -> dict:
+    """The page geometry each paged-kernel call sees in ``cache``'s pool,
+    as ``kernels.paged_kv_attention`` takes it: page size, KV heads (a
+    shard's under tensor parallelism, :func:`_kernel_tp`), stored words a
+    head and the container's bits."""
+    *_, ps, kv, words = cache["k_pages"].shape
+    return {"page_size": ps, "kv_heads": kv // _kernel_tp(cache, mesh),
+            "words": words,
+            "bits": {"int8": 8, "int4": 4, "fp": 0}[_paged_container(cache)]}
+
+
+def paged_kernel_work(cache, page_table, cache_pos, seq_len: int):
+    """The Pallas paged kernel's work list
+    (``kernels.paged_kv_attention.work_list``) for a chunk of ``seq_len``
+    queries from ``cache_pos`` (scalar or (B,)) over ``cache``'s pool, as
+    each layer's kernel call (each shard's, under tensor parallelism)
+    walks it. Every layer of a step walks the same table, so the layer
+    scan builds it once (``models.transformer._segment_scan``)."""
+    from ..kernels.paged_kv_attention import work_list
+    B, NP = page_table.shape
+    base = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1),
+                            (B,))
+    return work_list(base, base + seq_len, seq_len=seq_len,
+                     num_table_pages=NP, **paged_kernel_pages(cache))
+
+
+def _paged_kernel_attention(q, cache, page_table, base, layer, work=None):
     """The Pallas paged kernel on the chunk ``q`` (B, S, H, hd), reading
     layer ``layer`` of the stacked pool in place (a single layer's pool,
-    ``layer=None``, is read as a stack of one). Under a mesh context whose
-    "model" axis divides the KV heads (tensor-parallel serving: pools
-    sharded on KV heads, queries on heads), the kernel runs per shard
-    through ``shard_map`` on that shard's heads and pages, so the
-    partitioner never gathers the pools onto one device."""
+    ``layer=None``, is read as a stack of one), over the step's ``work``
+    list (:func:`paged_kernel_work`; the kernel builds it when None).
+    Under a mesh context whose "model" axis divides the KV heads
+    (tensor-parallel serving: pools sharded on KV heads, queries on
+    heads), the kernel runs per shard through ``shard_map`` on that
+    shard's heads and pages, so the partitioner never gathers the pools
+    onto one device."""
     from ..kernels.ops import paged_kv_attention_chunk
     S = q.shape[1]
-    bits = {"int8": 8, "int4": 4, "fp": 0}[_paged_container(cache)]
+    bits = paged_kernel_pages(cache)["bits"]
     pools = [cache[f] for f in ("k_pages", "v_pages", "k_scale", "v_scale")]
     if layer is None:
         pools, layer = [a[None] for a in pools], 0
 
-    def kernel(q, k_pages, v_pages, k_scale, v_scale, pt, base, layer):
+    def kernel(q, k_pages, v_pages, k_scale, v_scale, pt, base, layer, work):
         return paged_kv_attention_chunk(q, k_pages, v_pages, k_scale,
                                         v_scale, pt, base, base + S,
-                                        layer=layer, bits=bits)
+                                        layer=layer, work=work, bits=bits)
 
-    args = (q, *pools, page_table, base, jnp.asarray(layer, jnp.int32))
-    mesh = jax.sharding.get_abstract_mesh()
-    tp = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
-    if tp > 1 and cache["k_pages"].shape[-2] % tp == 0:
+    args = (q, *pools, page_table, base, jnp.asarray(layer, jnp.int32),
+            work)
+    if _kernel_tp(cache) > 1:
         from jax.sharding import PartitionSpec as P
         heads = P(None, None, "model", None)
         pool = P(None, None, None, "model", None)
         kernel = jax.shard_map(
-            kernel, mesh=mesh,
-            in_specs=(heads, pool, pool, P(), P(), P(), P(), P()),
+            kernel, mesh=jax.sharding.get_abstract_mesh(),
+            in_specs=(heads, pool, pool, P(), P(), P(), P(), P(), P()),
             out_specs=heads, check_vma=False)
     return kernel(*args).astype(q.dtype)
 
@@ -418,7 +457,7 @@ def gqa_apply(params, x, positions, *, cfg, cache=None, cache_pos=None,
               kv_quant: Optional[KVQuantSpec] = None, mrope_positions=None,
               chunked: Optional[bool] = None, page_table=None,
               attn_impl: str = "gather", kv_valid_len=None,
-              cache_layer=None):
+              cache_layer=None, attn_work=None):
     """Returns (y, new_cache). ``positions``: (B, S) absolute positions.
 
     Train/prefill: cache=None -> attends within the sequence (causal per cfg),
@@ -439,7 +478,8 @@ def gqa_apply(params, x, positions, *, cfg, cache=None, cache_pos=None,
     ``cache_layer`` (scalar, may be traced) marks a paged ``cache`` as the
     whole stacked ``(L, P, ...)`` pool of the layer scan and names this
     layer's index in it: the pool is written and read in place, and the
-    whole stacked pool is returned.
+    whole stacked pool is returned. ``attn_work``: the Pallas kernel's work
+    list for the step (``paged_kernel_work``), None to build it per call.
     """
     B, S, D = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -481,7 +521,8 @@ def gqa_apply(params, x, positions, *, cfg, cache=None, cache_pos=None,
         # float-tolerance equal) or the jnp gather path (bitwise reference)
         o = route_paged_attention(q, new_cache, page_table, positions,
                                   cache_pos, cfg=cfg, attn_impl=attn_impl,
-                                  operand_dtype=odt, layer=cache_layer)
+                                  operand_dtype=odt, layer=cache_layer,
+                                  work=attn_work)
     elif cache is not None:
         pos = cache_pos
         new_cache = cache_update(cache, k, v, pos, kv_quant)

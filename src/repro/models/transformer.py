@@ -28,7 +28,7 @@ from ..core.paged_kv import PagedCacheSpec
 from ..parallel.hints import constrain
 from .attention import (KVQuantSpec, gqa_apply, init_gqa, init_kv_cache,
                         init_mla, init_mla_cache, init_paged_kv_cache,
-                        mla_apply)
+                        mla_apply, paged_kernel_work)
 from .common import (chunked_ce_loss, cross_entropy, dense_init, embed_tokens,
                      init_embedding, init_lm_head, init_rmsnorm, lm_head,
                      rmsnorm)
@@ -207,14 +207,16 @@ def init_block(key, cfg, sig):
 def block_apply(params, x, positions, *, cfg, sig, cache=None, cache_pos=None,
                 quant: Optional[ModelQuant] = None, mrope_positions=None,
                 page_table=None, attn_impl: str = "gather",
-                kv_valid_len=None, cache_layer=None):
+                kv_valid_len=None, cache_layer=None, attn_work=None):
     """Returns (x, new_cache, aux). ``quant`` holds per-THIS-layer scalars.
 
     ``attn_impl``/``kv_valid_len`` only affect paged GQA attention: kernel
     vs gather routing (one variable-length path for chunk prefill AND
     decode) and padded-chunk masking (see ``attention.gqa_apply``).
     ``cache_layer`` is this layer's index in a stacked paged ``cache``
-    (``_segment_scan``), which is then updated in place and returned whole.
+    (``_segment_scan``), which is then updated in place and returned whole;
+    ``attn_work`` is the Pallas kernel's work list the scan built for the
+    step (``attention.paged_kernel_work``).
     """
     kind, ffn = sig
     aux = {}
@@ -244,7 +246,8 @@ def block_apply(params, x, positions, *, cfg, sig, cache=None, cache_pos=None,
                                      page_table=page_table,
                                      attn_impl=attn_impl,
                                      kv_valid_len=kv_valid_len,
-                                     cache_layer=cache_layer)
+                                     cache_layer=cache_layer,
+                                     attn_work=attn_work)
     elif kind == "mamba":
         y, new_cache = mamba_apply(params["mixer"], h, cfg=cfg, state=cache,
                                    state_quant=state_quant)
@@ -425,6 +428,10 @@ def _segment_scan(seg_params, x, positions, *, cfg, pattern, start, periods,
     paged = tuple(isinstance(c, dict) and "k_pages" in c for c in caches)
     pools = tuple(c if p else None for c, p in zip(caches, paged))
     scanned = tuple(None if p else c for c, p in zip(caches, paged))
+    # every layer's kernel walks the same table: its work list, once
+    work = (paged_kernel_work(pools[paged.index(True)], page_table,
+                              cache_pos, x.shape[1])
+            if attn_impl == "pallas" and any(paged) else None)
 
     def body(carry, xs):
         x, pools = carry
@@ -438,7 +445,7 @@ def _segment_scan(seg_params, x, positions, *, cfg, pattern, start, periods,
                 cache_pos=cache_pos, quant=q_i,
                 mrope_positions=mrope_positions, page_table=page_table,
                 attn_impl=attn_impl, kv_valid_len=kv_valid_len,
-                cache_layer=period if paged[pi] else None)
+                cache_layer=period if paged[pi] else None, attn_work=work)
             if paged[pi]:
                 pools[pi], nc = nc, None
             new_caches.append(nc)

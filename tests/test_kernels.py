@@ -394,23 +394,101 @@ def test_page_walk_counts_live_extents():
     """The serving loop's host-side count of the kernel's walk: each query
     block's live extent, against a brute-force count of the table entries
     that block's queries can see (a chunk ending past the table sees all of
-    them); the table side is every entry a block."""
+    them); the table side is every entry a block; the grid steps are each
+    extent in blocks of ``ppb`` entries, the box every block of every
+    query block."""
     from repro.kernels.paged_kv_attention import page_walk
-    ps, np_, S, bq = 16, 12, 20, 8
+    ps, np_, S, bq, ppb = 16, 12, 20, 8, 5
     starts = np.asarray([0, 5, 100, 170, 188], np.int32)
     lens = starts + S - np.asarray([0, 3, 0, 9, 0])
-    want = 0
+    want = steps = 0
     for st, ln in zip(starts, lens):
         for qb in range(-(-S // bq)):
             last_q = st + min(S, (qb + 1) * bq) - 1      # causal bound
-            want += min(-(-min(int(ln), last_q + 1) // ps), np_)
-    walked, table = page_walk(starts, lens, seq_len=S, num_table_pages=np_,
-                              page_size=ps, block_q=bq)
+            n = min(-(-min(int(ln), last_q + 1) // ps), np_)
+            want, steps = want + n, steps + -(-n // ppb)
+    walked, table, got_steps, box = page_walk(
+        starts, lens, seq_len=S, num_table_pages=np_, page_size=ps, ppb=ppb,
+        block_q=bq)
     assert table == 5 * 3 * np_
     assert walked == want
+    assert (got_steps, box) == (steps, 5 * 3 * 3)
     dec = page_walk(starts, starts + 1, seq_len=1, num_table_pages=np_,
-                    page_size=ps)
-    assert dec == (int((starts // ps + 1).sum()), 5 * np_)
+                    page_size=ps, ppb=ppb)
+    n = starts // ps + 1
+    assert dec == (int(n.sum()), 5 * np_, int((-(-n // ppb)).sum()), 5 * 3)
+
+
+_WORK_CASES = {
+    # serving decode: 30 free slots (kv_len 1) beside a full and a ragged
+    # row
+    "decode-free-slots": ([0] * 30 + [319, 150], [1] * 30 + [320, 151], 1,
+                          1),
+    # a chunk of 3 query blocks straddling block edges, one row past the
+    # table's end
+    "chunk": ([0, 37, 122, 300], [20, 57, 142, 320], 20, 8),
+    # every row at the full table: the list is the whole box
+    "full": ([312, 312, 312], [320, 320, 320], 8, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(_WORK_CASES))
+def test_work_list_matches_brute_force(case):
+    """The kernel's compacted grid against a brute-force enumeration of the
+    live (row, query block, page block) triples, in (row, query block,
+    block) order: each query block's items start at block 0 and end at its
+    last live block, the count is the sum of its extents in blocks (at
+    least one a query block; the whole box when every row is full), the
+    host-side count agrees, and the padding repeats the last item."""
+    from repro.kernels.paged_kv_attention import (live_extent, page_walk,
+                                                  unpack_item, work_list)
+    starts, lens, S, bq = _WORK_CASES[case]
+    ps, np_, ppb = 8, 40, 16
+    nq, nb = -(-S // bq), -(-np_ // ppb)
+    want = []
+    for b, (st, ln) in enumerate(zip(starts, lens)):
+        for qb in range(nq):
+            n = int(live_extent(st, ln, qb, ps=ps, bq=bq,
+                                num_table_pages=np_, xp=np))
+            want += [(b, qb, i, n) for i in range(-(-n // ppb))]
+    # int8 pages of 2 KV heads: 128 keys a block, 16 pages of 8
+    steps, keys, extents = work_list(
+        jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32),
+        seq_len=S, num_table_pages=np_, page_size=ps, kv_heads=2, words=16,
+        bits=8, block_q=bq)
+    got = np.stack([*unpack_item(np.asarray(keys), nq=nq, nb=nb),
+                    np.asarray(extents)], axis=1)
+    box = len(starts) * nq * nb
+    assert got.shape == (box, 4) and int(steps) == len(want)
+    assert [tuple(r) for r in got[:len(want)]] == want
+    assert (got[len(want):] == got[len(want) - 1]).all()
+    assert len(want) >= len(starts) * nq
+    if case == "full":
+        assert len(want) == box
+    walk = page_walk(starts, lens, seq_len=S, num_table_pages=np_,
+                     page_size=ps, ppb=ppb, block_q=bq)
+    assert walk[2:] == (len(want), box)
+
+
+@pytest.mark.parametrize("bits", [8, 4, 0], ids=["int8", "int4", "fp"])
+@pytest.mark.parametrize("s", [1, 8], ids=["decode", "chunk8"])
+def test_paged_kernel_free_slots(s, bits):
+    """The serving decode's shape: 32 rows, 30 of them free slots at kv_len
+    1 beside one row at the full table and one ragged row, so the kernel's
+    work list skips most of the box; against the oracle, every container,
+    at decode and at an 8-token chunk."""
+    rng = np.random.default_rng(s * 7 + bits)
+    B, kv, g, hd, ps, np_ = 32, 2, 2, 16, 8, 40
+    lens = np.asarray([1] * 30 + [np_ * ps, 151], np.int32)
+    starts = np.maximum(lens - s, 0).astype(np.int32)
+    kq, vq, ks, vs, pt = _mk_fragmented_pool(rng, B, np_, ps, kv, hd, bits)
+    q = jnp.asarray(rng.normal(size=(B, s, kv * g, hd)), jnp.float32)
+    out = ops.paged_kv_attention_chunk(q, kq, vq, ks, vs, jnp.asarray(pt),
+                                       jnp.asarray(starts),
+                                       jnp.asarray(lens), bits=bits)
+    expect = ref.paged_kv_attention_chunk_ref(q, kq, vq, ks, vs, pt, starts,
+                                              lens, bits=bits)
+    np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
 
 
 def test_kv_attention_masks_tail():

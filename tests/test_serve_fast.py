@@ -520,11 +520,11 @@ def test_pallas_attn_impl_serving_smoke(smoke_model):
     rng = np.random.default_rng(5)
     mk = lambda: [Request(i, rng.integers(0, cfg.vocab_size, 6)
                           .astype(np.int32), 6) for i in range(4)]
-    a = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
+    a = BatchedServer(cfg, params, batch_size=2, max_len=256, kv_bits=8,
                       page_size=8, attn_impl="gather")
     rng = np.random.default_rng(5)
     out_a = a.run(mk())
-    b = BatchedServer(cfg, params, batch_size=2, max_len=32, kv_bits=8,
+    b = BatchedServer(cfg, params, batch_size=2, max_len=256, kv_bits=8,
                       page_size=8, attn_impl="pallas")
     rng = np.random.default_rng(5)
     out_b = b.run(mk())
@@ -532,12 +532,15 @@ def test_pallas_attn_impl_serving_smoke(smoke_model):
                      for x, y in zip(out_a, out_b)])
     assert all(r.done for r in out_b)
     assert agree >= 0.9, agree
-    # the kernel's walk, per program call: short rows in a 4-entry table
-    walk = lambda srv: [srv.metrics.counter(f"serve.attn_pages_{k}").value
-                        for k in ("live", "table")]
-    live, table = walk(b)
+    # the kernel's walk, per program call: short rows in a 32-entry table
+    # of two page blocks (16 entries a grid step)
+    walk = lambda srv: [srv.metrics.counter(f"serve.attn_{k}").value
+                        for k in ("pages_live", "pages_table", "grid_steps",
+                                  "grid_box")]
+    live, table, steps, box = walk(b)
     assert 0 < live < table, (live, table)
-    assert walk(a) == [0, 0]            # the gather path runs no kernel
+    assert 0 < steps < box, (steps, box)
+    assert walk(a) == [0, 0, 0, 0]      # the gather path runs no kernel
 
 
 @pytest.mark.parametrize("kv_bits", [0, 8, 4])
